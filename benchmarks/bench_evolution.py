@@ -217,5 +217,7 @@ if __name__ == "__main__":
                     help="long horizon: enough cycles after each finetune "
                          "that quality_by_version shows >v0 rows")
     args = ap.parse_args()
+    from repro.session import enable_compilation_cache
+    enable_compilation_cache()
     print("name,us_per_call,derived")
     main(smoke=args.smoke, long=args.long)

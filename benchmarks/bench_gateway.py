@@ -157,4 +157,6 @@ def main(emit=print, argv=None):
 
 
 if __name__ == "__main__":
+    from repro.session import enable_compilation_cache
+    enable_compilation_cache()
     main()

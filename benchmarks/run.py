@@ -78,4 +78,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.session import enable_compilation_cache
+    enable_compilation_cache()
     main()

@@ -48,12 +48,13 @@ def foldscore_multimer_config() -> ModelConfig:
     staged binder protocols use it as the fold stage's second param set —
     a genuinely distinct model from the per-chain ``foldscore-s`` scorer,
     so the stage table exercises two configs, not just two inits."""
+    # segments re-cleared: the base materializes its 8-layer plan in
+    # __post_init__, which would contradict the deeper layer count
     return foldscore_config().replace(name="foldscore-m", n_layers=12,
-                                      d_ff=1536)
+                                      d_ff=1536, segments=())
 
 
 def foldscore_multimer_reduced() -> ModelConfig:
-    # segments re-cleared: the reduced base materializes a 2-layer plan in
-    # __post_init__, which would contradict the deeper layer count
+    # segments re-cleared, as above (the reduced base plans 2 layers)
     return foldscore_reduced().replace(name="foldscore-m", n_layers=3,
                                        segments=())

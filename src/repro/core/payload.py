@@ -77,12 +77,6 @@ gen_batch_log: List[dict] = []
 backbone_log: List[dict] = []
 
 
-class PagedEngineError(RuntimeError):
-    """The paged decode engine failed before any mid-decode admission —
-    ``generate_batch`` catches this and degrades the dispatch to the
-    dense per-row path instead of failing the tasks."""
-
-
 def _pad_rows(arrs: List[np.ndarray], rows: int):
     """Pad each array's leading dim from ``rows`` up to its bucket size by
     repeating the last real row (dropped again before results return).
@@ -495,23 +489,7 @@ class ProteinPayload:
         over a paged KV cache instead of per-row dense sampling.
         """
         if payload.get("decode") == "paged":
-            try:
-                return self._generate_batch_paged(submesh, payload)
-            except PagedEngineError as e:
-                # graceful degradation: a broken decode engine downgrades
-                # the dispatch to the dense per-row path instead of
-                # failing the tasks — results are still valid samples,
-                # just without continuous batching. Only raised when no
-                # queued task was admitted mid-decode (those would be
-                # lost); mid-flight failures go to the retry taxonomy.
-                print(f"[payload] paged decode failed ({e.__cause__!r}); "
-                      f"falling back to dense generate_batch", flush=True)
-                dense = {k: v for k, v in payload.items()
-                         if k not in ("decode", "page_size",
-                                      "decode_slots", "_admit")}
-                out = self.generate_batch(submesh, dense)
-                out["batch"]["decode"] = "dense_fallback"
-                return out
+            return self._generate_batch_paged(submesh, payload)
         bbs = np.asarray(payload["backbones"], np.float32)
         if bbs.ndim == 2:
             bbs = bbs[None]
@@ -674,14 +652,12 @@ class ProteinPayload:
         R0 = bbs.shape[0]
         slots = int(payload.get("decode_slots", 0)) \
             or min(max(R0 * n, 4), 32)
-        try:
-            eng = self._compiled(
-                f"paged{slots}_L{length}_p{page_size}{sfx}", dev,
-                lambda: prot.PagedDecodeEngine(
-                    gcfg, slots=slots, max_new=length,
-                    page_size=page_size, device=dev))
-        except Exception as e:   # engine build/compile failure: degradable
-            raise PagedEngineError("paged engine construction failed") from e
+        eng_kind = f"paged{slots}_L{length}_p{page_size}{sfx}"
+        eng = self._compiled(
+            eng_kind, dev,
+            lambda: prot.PagedDecodeEngine(
+                gcfg, slots=slots, max_new=length,
+                page_size=page_size, device=dev))
         ver, gparams = store.current()
         gp = self._params_on(("gen", ns, ver), gparams, dev)
 
@@ -716,13 +692,12 @@ class ProteinPayload:
                 res = eng.run(gp, temp,
                               specs=specs_for(bbs, seeds, row_lens, 0),
                               poll=poll)
-            except Exception as e:
-                if admitted:
-                    # queued tasks already joined this decode; degrading
-                    # now would drop their rows — let the retry taxonomy
-                    # handle the failure instead
-                    raise
-                raise PagedEngineError("paged decode run failed") from e
+            except Exception:
+                # a failed run leaves the engine's slots and donated device
+                # state half-updated: drop it so a retry builds a fresh one
+                with self._cache_lock:
+                    self._cache.pop((eng_kind, dev.id), None)
+                raise
         rows = []
         for tag0, nr in sorted(records):
             for r in range(nr):

@@ -1,44 +1,16 @@
-"""Small compat shims over jax.experimental.pallas API drift, plus the
-backend-aware ``interpret`` resolution every kernel wrapper shares."""
-
-import os
-
-from jax.experimental.pallas import tpu as pltpu
-
-# env override for interpret resolution: truthy forces interpret mode
-# everywhere, falsy forces the compiled path even off-TPU (debugging a
-# lowering), unset defers to the backend check.
-INTERPRET_ENV = "IMPRESS_PALLAS_INTERPRET"
-
-_TRUTHY = ("1", "true", "yes", "on")
-_FALSY = ("0", "false", "no", "off")
-
-
-def compiler_params(dimension_semantics):
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=dimension_semantics)
+"""The backend-aware ``interpret`` resolution every kernel wrapper shares."""
 
 
 def resolve_interpret(interpret=None) -> bool:
-    """Resolve an ``interpret=None`` kernel flag: the explicit arg wins,
-    then the ``IMPRESS_PALLAS_INTERPRET`` env var, then interpret on any
-    non-TPU backend (so CPU CI runs every Pallas kernel unflagged).
+    """Resolve an ``interpret=None`` kernel flag: an explicit argument
+    wins; otherwise interpret exactly when the backend is not a TPU (so
+    CPU tests run every Pallas kernel unflagged, and a TPU always runs
+    the compiled kernel).
 
     Must be called *outside* jit — the result feeds a static pallas_call
-    argument, and resolving inside a trace would freeze the env/backend
-    state of the first call into the cached executable."""
+    argument, and resolving inside a trace would freeze the backend state
+    of the first call into the cached executable."""
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get(INTERPRET_ENV)
-    if env is not None:
-        v = env.strip().lower()
-        if v in _TRUTHY:
-            return True
-        if v in _FALSY:
-            return False
-        raise ValueError(
-            f"{INTERPRET_ENV}={env!r}: expected one of "
-            f"{_TRUTHY + _FALSY}")
     import jax
     return jax.default_backend() != "tpu"

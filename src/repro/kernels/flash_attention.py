@@ -24,7 +24,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 
 NEG = -0.7 * float(np.finfo(np.float32).max)
 
@@ -112,7 +111,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0, softcap=0.0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

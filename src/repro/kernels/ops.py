@@ -6,10 +6,10 @@ kernel layout, pad sequence dims, invoke the kernel (TPU-compiled or
 interpret-on-CPU), and slice the padding back off.
 
 Every wrapper takes ``interpret=None`` meaning *auto*: interpret mode on
-any non-TPU backend, overridable with the ``IMPRESS_PALLAS_INTERPRET``
-env var (see ``_compat.resolve_interpret``). Resolution happens in the
-un-jitted wrapper — before tracing — so the flag is a plain static
-argument of the inner jitted function.
+any non-TPU backend, the compiled kernel on a TPU (see
+``_compat.resolve_interpret``). Resolution happens in the un-jitted
+wrapper — before tracing — so the flag is a plain static argument of the
+inner jitted function.
 """
 
 from __future__ import annotations

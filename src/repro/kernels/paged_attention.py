@@ -44,7 +44,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
 
 NEG_INF = -0.7 * float(np.finfo(np.float32).max)
 
@@ -147,7 +146,8 @@ def paged_decode_bkgh(q, k_pages, v_pages, block_tables, lengths, *,
     )
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = compiler_params(("parallel", "arbitrary"))
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,

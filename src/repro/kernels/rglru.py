@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
-
 
 def _kernel(a_ref, b_ref, h0_ref, h_ref, hT_ref, s_ref, *, block_t):
     it = pl.program_id(2)
@@ -66,7 +64,8 @@ def rglru_btc(a, b, h0, *, block_t=256, block_c=128, interpret=False):
             jax.ShapeDtypeStruct((B, C), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_c), jnp.float32)],
-        compiler_params=compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)
     return h, hT

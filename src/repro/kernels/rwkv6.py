@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_ref,
             *, chunk):
@@ -92,7 +90,8 @@ def wkv6_bhtk(r, k, v, logw, u, s0, *, chunk=32, interpret=False):
             jax.ShapeDtypeStruct((B, H, K, K), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
-        compiler_params=compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u, s0)
     return y, sT

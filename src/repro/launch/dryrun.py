@@ -6,12 +6,16 @@ Usage:
       --shape train_4k --mesh single --out results/dryrun
   PYTHONPATH=src python -m repro.launch.dryrun --all  # subprocess per cell
 
-The first two lines below MUST stay the first two lines: jax locks the
-device count at first init, and the dry-run (only the dry-run) needs 512
-placeholder CPU devices to build the production meshes.
+The environment lines below MUST stay above every jax import: jax locks
+the platform and device count at first init, and the dry-run (only the
+dry-run) needs 512 placeholder CPU devices to build the production meshes.
+They pin the CPU even on a machine with a TPU, for this process and the
+per-cell children it spawns (which inherit the environment): a TPU can
+belong to one process only, and the dry-run never needs it.
 """
 
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
 

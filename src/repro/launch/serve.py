@@ -11,7 +11,7 @@ flag serves IM-RP, the CONT-V control, the multi-objective demo, or any
 mix of them concurrently on one pilot:
 
   PYTHONPATH=src python -m repro.launch.serve --campaign im-rp,cont-v \\
-      --structures 4 --cycles 3 [--evolution]
+      --structures 4 --cycles 3 [--evolution] [--reduced]
 
 Ctrl-C in campaign mode is graceful: the campaign is checkpointed (to
 ``--checkpoint-out``) and the partial report printed before exiting, so
@@ -22,7 +22,11 @@ resident runtime, campaigns submitted over a JSON HTTP API, co-tenant
 same-bucket batches fused across campaigns:
 
   PYTHONPATH=src python -m repro.launch.serve --gateway --port 8642 \\
-      [--tokens tok-a=alice,tok-b=bob] [--quota alice=2.0:4]
+      [--tokens tok-a=alice,tok-b=bob] [--quota alice=2.0:4] [--reduced]
+
+Every mode runs the configured model widths unless ``--reduced`` asks for
+the reduced-scale models, and keeps XLA's persistent compilation cache
+where ``repro.session.enable_compilation_cache`` puts it.
 
 The CLI is deliberately thin: every behavior lives in
 ``repro.gateway.GatewayService``; this file only parses flags, prints
@@ -84,8 +88,8 @@ def serve_batch(cfg, *, batch, prompt_len, gen, temperature=0.0, seed=0):
 
 
 def serve_campaign(*, protocols, structures, cycles, candidates,
-                   receptor_len, evolution, timeout=600.0, trace_dir=None,
-                   metrics_every=0.0,
+                   receptor_len, evolution, reduced=False, timeout=600.0,
+                   trace_dir=None, metrics_every=0.0,
                    checkpoint_out="impress-checkpoint.json"):
     """Run a design campaign through the session facade and return its
     versioned report. ``trace_dir`` enables span tracing (Perfetto JSON +
@@ -105,7 +109,8 @@ def serve_campaign(*, protocols, structures, cycles, candidates,
         protocols=tuple(ProtocolSpec(kind, n_candidates=candidates,
                                      n_cycles=cycles)
                         for kind in protocols),
-        evolution=evolution, timeout=timeout, trace_dir=trace_dir)
+        evolution=evolution, reduced=reduced, timeout=timeout,
+        trace_dir=trace_dir)
     with ImpressSession(spec) as session:
         stop = threading.Event()
         if metrics_every > 0:
@@ -136,7 +141,7 @@ def serve_campaign(*, protocols, structures, cycles, candidates,
 
 
 def serve_gateway(*, host="127.0.0.1", port=8642, tokens=None, quotas=None,
-                  max_workers=8, reduced=True, payload_length=64,
+                  max_workers=8, reduced=False, payload_length=64,
                   trace_dir=None, checkpoint_dir=None):
     """Start the persistent gateway + its HTTP front-end and block until
     Ctrl-C, which drains gracefully: every live campaign is checkpointed
@@ -225,6 +230,8 @@ def main():
                     help="gateway mode: Ctrl-C writes every live "
                          "campaign's checkpoint here")
     args = ap.parse_args()
+    from repro.session import enable_compilation_cache
+    enable_compilation_cache()
     if args.gateway:
         from repro.gateway import TenantQuota
         quotas = None
@@ -237,7 +244,7 @@ def main():
                     max_devices=int(cap) if cap else None)
         serve_gateway(host=args.host, port=args.port,
                       tokens=_parse_kv(args.tokens, "tokens"),
-                      quotas=quotas,
+                      quotas=quotas, reduced=args.reduced,
                       trace_dir=args.trace_dir,
                       checkpoint_dir=args.checkpoint_dir)
         return
@@ -247,6 +254,7 @@ def main():
                              candidates=args.candidates,
                              receptor_len=args.receptor_len,
                              evolution=args.evolution,
+                             reduced=args.reduced,
                              trace_dir=args.trace_dir,
                              metrics_every=args.metrics_every,
                              checkpoint_out=args.checkpoint_out)

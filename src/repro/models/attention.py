@@ -254,7 +254,6 @@ def attn_fwd(p, x, positions, cfg, *, causal=True, window=0, kv_x=None,
     if cfg.attn_impl in ("pallas", "pallas_interpret") and causal and kv_x is x:
         from repro.kernels import ops as kops
         # "pallas" auto-resolves: compiled on TPU, interpret elsewhere
-        # (overridable via IMPRESS_PALLAS_INTERPRET — see kernels/_compat)
         out = kops.flash_attention(
             q, k, v, causal=True, window=window,
             softcap=cfg.attn_logit_softcap,

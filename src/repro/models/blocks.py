@@ -256,9 +256,7 @@ def _remat(fn, cfg):
         return fn
     if cfg.remat == "full":
         return jax.checkpoint(fn)
-    pol = getattr(jax.checkpoint_policies, "dots_saveable", None) or \
-        jax.checkpoint_policies.checkpoint_dots
-    return jax.checkpoint(fn, policy=pol)
+    return jax.checkpoint(fn, policy=jax.checkpoint_policies.dots_saveable)
 
 
 def segment_fwd(seg_params, x, kinds, ctx, cfg):

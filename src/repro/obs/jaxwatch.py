@@ -3,14 +3,15 @@ compilation events.
 
 ``CompileWatcher`` bridges two probe styles into the metrics registry:
 
-* ``jax.monitoring`` listeners (when this jax version exposes them):
-  every backend-compile duration event increments
-  ``jax.compiles{event=...}`` and feeds ``jax.compile_s`` — catching
-  *every* trace/compile in the process, including retraces the payload
-  layer never sees. Listener registration is process-global and most jax
-  versions cannot unregister, so one module-level listener fans out to
-  whichever watchers are currently active (the context manager toggles an
-  active flag instead of re-registering).
+* ``jax.monitoring`` listeners: every trace/lower/compile duration event
+  increments ``jax.compiles{event=...}`` and feeds
+  ``jax.compile_s{event=...}`` (``backend_compile_duration`` is one XLA
+  compile or persistent-cache load, ``cache_retrieval_time_sec`` one
+  cache hit) — catching *every* trace/compile in the process, including
+  retraces the payload layer never sees. Listener registration is
+  process-global and cannot be undone, so one module-level listener fans
+  out to whichever watchers are currently active (the context manager
+  toggles an active flag instead of re-registering).
 * ``trace_counts``-style probes: explicit counters owned by long-lived
   engines (e.g. ``PagedDecodeEngine.trace_counts``) — ``absorb_counts``
   folds their deltas in under ``jax.traces{probe=..., event=...}``.
@@ -41,19 +42,15 @@ def _on_event_duration(event: str, duration: float, **kw) -> None:
         w._record(event, duration)
 
 
-def _install_listener() -> bool:
-    """Register the module-level jax.monitoring listener once. Returns
-    whether this jax version supports duration listeners."""
+def _install_listener() -> None:
+    """Register the module-level jax.monitoring listener once."""
     global _listener_installed
-    if _listener_installed:
-        return True
-    try:
+    with _lock:
+        if _listener_installed:
+            return
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(_on_event_duration)
-    except Exception:        # noqa: BLE001 — jax version without monitoring
-        return False
-    _listener_installed = True
-    return True
+        _listener_installed = True
 
 
 class CompileWatcher:
@@ -62,20 +59,18 @@ class CompileWatcher:
         with CompileWatcher(registry):
             ...  # jitted calls; compiles land in jax.compiles / jax.compile_s
 
-    Inactive watchers cost nothing; when jax.monitoring is unavailable the
-    watcher degrades to the explicit ``absorb_*`` probes only
-    (``supported`` is False).
+    Inactive watchers cost nothing.
     """
 
     def __init__(self, registry):
         self.registry = registry
-        self.supported = False
         self._counts_seen: Dict[tuple, float] = {}
 
     def _record(self, event: str, duration: float) -> None:
         short = event.rsplit("/", 1)[-1] or event
         self.registry.counter("jax.compiles", event=short).inc()
-        self.registry.histogram("jax.compile_s").observe(float(duration))
+        self.registry.histogram("jax.compile_s",
+                                event=short).observe(float(duration))
 
     def absorb_counts(self, probe: str, counts: Dict[str, int]) -> None:
         """Fold a ``trace_counts``-style monotonically-growing counter dict
@@ -105,7 +100,7 @@ class CompileWatcher:
                     h.observe(float(w))
 
     def __enter__(self) -> "CompileWatcher":
-        self.supported = _install_listener()
+        _install_listener()
         with _lock:
             _active.append(self)
         return self

@@ -133,11 +133,6 @@ class CampaignSpec:
     reduced: bool = True                   # reduced-scale payload models
     seed: int = 0
     timeout: float = 600.0
-    # XLA persistent compilation cache: repeat campaigns (and per-sub-mesh
-    # finetune recompiles) reuse compiled executables across processes
-    # instead of paying multi-second "Exec setup" on every run. None falls
-    # back to $IMPRESS_COMPILATION_CACHE; empty/unset disables.
-    compilation_cache_dir: Optional[str] = None
     # Span tracing + Perfetto export: when set (or via $IMPRESS_TRACE_DIR),
     # the session enables the obs.Tracer and run() writes trace.json
     # (chrome://tracing / ui.perfetto.dev loadable) and metrics.json there.
@@ -253,21 +248,33 @@ def _normalize_protocols(spec: CampaignSpec) -> List[ProtocolSpec]:
     return out
 
 
-def _enable_compilation_cache(jax, path: str):
-    """Point XLA's persistent compilation cache at ``path`` (created if
-    missing) and drop the size/compile-time floors so even reduced-scale
-    test executables are cached — repeat campaigns and the finetune
-    per-sub-mesh recompile then load compiled code from disk instead of
-    recompiling. Floor knobs vary across jax versions; missing ones are
-    skipped."""
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    for knob, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                      ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(knob, val)
-        except (AttributeError, ValueError):
-            pass
+# Where XLA's persistent compilation cache lives when
+# JAX_COMPILATION_CACHE_DIR is unset: a fixed directory inside the
+# checkout (git-ignored), so every run from one checkout finds what the
+# runs before it compiled.
+DEFAULT_COMPILATION_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
+    """Turn on XLA's persistent compilation cache for this process and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR``, which JAX reads
+    itself, wins; otherwise the cache goes to
+    ``DEFAULT_COMPILATION_CACHE``. The size and compile-time floors drop
+    to zero so every executable is cached: later runs, and later payloads
+    of one run (the gateway's, the finetune sub-mesh recompiles), load
+    compiled code from disk instead of compiling again. Call it before the
+    first compile: JAX fixes the cache when it first uses it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    path = jax.config.jax_compilation_cache_dir
+    if not path:
+        path = DEFAULT_COMPILATION_CACHE
+        compilation_cache.set_cache_dir(path)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 # -- the report -------------------------------------------------------------
@@ -380,11 +387,6 @@ class ImpressSession:
         # was built when folding compile walls into the metrics registry
         self._compile_log_start = {k: len(v) for k, v
                                    in payload_mod.compile_log.items()}
-        self.compilation_cache_dir = (
-            spec.compilation_cache_dir
-            or os.environ.get("IMPRESS_COMPILATION_CACHE") or None)
-        if self.compilation_cache_dir:
-            _enable_compilation_cache(jax, self.compilation_cache_dir)
         self.length_buckets = campaign_length_buckets(spec)
         self.payload = payload if payload is not None else ProteinPayload(
             jax.random.PRNGKey(spec.seed), reduced=spec.reduced,
@@ -497,8 +499,9 @@ class ImpressSession:
                 timeout=self.spec.timeout if timeout is None else timeout)
             watcher.absorb_compile_log(payload_mod.compile_log,
                                        self._compile_log_start)
+        import jax
         raw["compile"] = {
-            "persistent_cache_dir": self.compilation_cache_dir,
+            "persistent_cache_dir": jax.config.jax_compilation_cache_dir,
             "mean_exec_setup_s": raw["executor"]["mean_exec_setup_s"],
             "length_buckets": (list(self.length_buckets)
                                if self.length_buckets else None),

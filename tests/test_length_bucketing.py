@@ -2,9 +2,12 @@
 equivalence (a padded mixed-length batch scores bit-close to each row
 alone at its true length), coalesce-rule key/merge/split round-trips over
 heterogeneous lengths, batch-composition independence of masked sampling,
-and the mixed-length campaign end to end through the session facade."""
+the mixed-length campaign end to end through the session facade, and
+where the persistent compilation cache lives."""
 
 import os
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -14,13 +17,16 @@ from repro.core import ProteinPayload, Task
 from repro.core.payload import (generate_batch_coalesce_rule,
                                 predict_batch_coalesce_rule)
 from repro.models import protein as prot
+from repro import session as session_mod
 from repro.runtime import DeviceAllocator
 from repro.runtime.allocator import (LENGTH_BUCKETS, bucket_len,
                                      choose_length_buckets)
-from repro.session import (CampaignSpec, ImpressSession, ProtocolSpec,
-                           campaign_length_buckets)
+from repro.session import (DEFAULT_COMPILATION_CACHE, CampaignSpec,
+                           ImpressSession, ProtocolSpec,
+                           campaign_length_buckets, enable_compilation_cache)
 
 ATOL = 1e-5
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # -- bucket tables -----------------------------------------------------------
@@ -292,21 +298,62 @@ def test_mixed_length_campaign_end_to_end():
     assert rep["compile"]["length_buckets"] == list(sess.length_buckets)
 
 
-def test_compilation_cache_opt_in(tmp_path):
-    """The XLA persistent-cache satellite: a spec-level cache dir is
-    applied to jax.config and recorded in the report's compile section."""
-    cache = str(tmp_path / "xla-cache")
+def _restore_cache_config(saved):
+    from jax.experimental.compilation_cache import compilation_cache
+    for knob, val in saved.items():
+        jax.config.update(knob, val)
+    compilation_cache.reset_cache()
+
+
+_CACHE_KNOBS = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_entry_size_bytes",
+                "jax_persistent_cache_min_compile_time_secs")
+
+
+def test_compilation_cache_opt_in(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR unset, enable_compilation_cache()
+    puts XLA's persistent cache at the fixed in-checkout directory, and a
+    campaign report records that effective directory. (The campaign's
+    cache is redirected to a temporary directory, so the test writes
+    nothing into the checkout.)"""
+    assert DEFAULT_COMPILATION_CACHE == os.path.join(REPO_ROOT, ".jax_cache")
+    monkeypatch.setattr(session_mod, "DEFAULT_COMPILATION_CACHE",
+                        str(tmp_path / ".jax_cache"))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    saved = {k: getattr(jax.config, k) for k in _CACHE_KNOBS}
+    jax.config.update("jax_compilation_cache_dir", None)
     spec = CampaignSpec(
         structures=1, receptor_len=8, peptide_len=4,
         protocols=(ProtocolSpec("im-rp", n_cycles=1, n_candidates=2),),
-        max_workers=2, compilation_cache_dir=cache)
+        max_workers=2)
     try:
         with ImpressSession(spec) as sess:
-            assert jax.config.jax_compilation_cache_dir == cache
-            assert os.path.isdir(cache)
+            # a session alone leaves the cache off
+            assert sess.run(timeout=120)["compile"][
+                "persistent_cache_dir"] is None
+        path = enable_compilation_cache()
+        assert path == str(tmp_path / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        with ImpressSession(spec) as sess:
             rep = sess.run(timeout=120)
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-    assert rep["compile"]["persistent_cache_dir"] == cache
-    # sessions without the opt-in record None (and leave config alone)
-    assert CampaignSpec().compilation_cache_dir is None
+        _restore_cache_config(saved)
+    assert rep["compile"]["persistent_cache_dir"] == path
+
+
+def test_compilation_cache_honours_env(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's own setting is the cache
+    directory and the helper sets no other path (a fresh process, since
+    JAX reads the variable when it is imported)."""
+    cache = str(tmp_path / "xla-cache")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache,
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(REPO_ROOT, "src"))
+    code = ("import jax; from repro.session import enable_compilation_cache;"
+            " print(enable_compilation_cache());"
+            " print(jax.config.jax_compilation_cache_dir)")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [cache, cache]

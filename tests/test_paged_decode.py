@@ -3,8 +3,7 @@ vectorized fallback and a dense oracle, paged vs dense ``lm.decode_step``
 model parity, engine page-pool round-trip (retire frees pages, re-admit
 reuses them), composition independence (identical tokens solo vs joining
 mid-flight) with the zero-recompile probe, the payload/executor live
-admission path, solo-predict length bucketing, and the
-``IMPRESS_PALLAS_INTERPRET`` override."""
+admission path, solo-predict length bucketing, and the interpret rule."""
 
 import dataclasses
 
@@ -18,7 +17,7 @@ from repro.configs.registry import get_reduced
 from repro.core import ProteinPayload, ResourceRequest, Task
 from repro.core.payload import _fold_in_keys, gen_batch_log
 from repro.kernels import paged_attention as pa
-from repro.kernels._compat import INTERPRET_ENV, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 from repro.models import lm
 from repro.models import protein as prot
 from repro.runtime import AsyncExecutor, DeviceAllocator
@@ -248,6 +247,27 @@ def test_payload_paged_rows_and_live_admission():
     assert eng.trace_counts == {"admit": 1, "step": 1}
 
 
+def test_paged_run_failure_fails_dispatch_and_evicts_engine(monkeypatch):
+    """A paged engine that fails mid-run fails its dispatch (there is no
+    dense re-run to hide it), and the half-updated engine leaves the cache
+    so the retry builds a fresh one."""
+    pp = ProteinPayload(jax.random.PRNGKey(0), reduced=True, length=6)
+    mesh = _Mesh()
+    key = ("paged4_L6_p8", mesh.devices.flat[0].id)
+
+    def broken_run(self, *a, **kw):
+        raise RuntimeError("decode step failed")
+
+    monkeypatch.setattr(prot.PagedDecodeEngine, "run", broken_run)
+    with pytest.raises(RuntimeError, match="decode step failed"):
+        pp.generate_batch(mesh, _gen_payload(0))
+    assert key not in pp._cache
+    monkeypatch.undo()
+    out = pp.generate_batch(mesh, _gen_payload(0))
+    assert out["batch"]["decode"] == "paged" and len(out["rows"]) == 1
+    assert key in pp._cache
+
+
 def test_executor_live_admission_end_to_end():
     """A task submitted while a live-rule paged dispatch is running joins
     that dispatch through the AdmissionPort: both tasks complete, the
@@ -282,7 +302,7 @@ def test_executor_live_admission_end_to_end():
 
 
 # ---------------------------------------------------------------------------
-# satellites: solo-predict bucketing, interpret override
+# satellites: solo-predict bucketing, interpret rule
 # ---------------------------------------------------------------------------
 
 def test_solo_predict_shares_bucketed_executable():
@@ -305,14 +325,11 @@ def test_solo_predict_shares_bucketed_executable():
 
 
 def test_resolve_interpret_env_override(monkeypatch):
-    assert resolve_interpret(True) is True
-    assert resolve_interpret(False) is False
-    monkeypatch.setenv(INTERPRET_ENV, "0")
-    assert resolve_interpret(None) is False
-    monkeypatch.setenv(INTERPRET_ENV, "yes")
-    assert resolve_interpret(None) is True
-    monkeypatch.setenv(INTERPRET_ENV, "maybe")
-    with pytest.raises(ValueError):
-        resolve_interpret(None)
-    monkeypatch.delenv(INTERPRET_ENV)
+    """An explicit flag wins; otherwise interpret exactly off-TPU, so a
+    TPU always runs the compiled kernel."""
     assert resolve_interpret(None) is (jax.default_backend() != "tpu")
+    for backend, want in (("cpu", True), ("gpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert resolve_interpret(None) is want
+        assert resolve_interpret(True) is True
+        assert resolve_interpret(False) is False

@@ -48,12 +48,13 @@ def test_cache_spec_kv_fallback_to_head_dim():
         axis_names = ("data", "model")
         devices = np.empty((16, 16), dtype=object)
 
+    # PartitionSpec normalises a one-axis tuple entry to the bare name
     spec = shd.cache_spec("segments/0/0_attn/k", (32, 128, 32768, 8, 128),
                           M(), cfg)
-    assert spec[-2] is None and spec[-1] == ("model",)
+    assert spec[-2] is None and spec[-1] == "model"
     spec2 = shd.cache_spec("segments/0/0_attn/k", (32, 128, 32768, 16, 128),
                            M(), cfg)
-    assert spec2[-2] == ("model",)
+    assert spec2[-2] == "model"
 
 
 def test_constrain_is_noop_without_context():
