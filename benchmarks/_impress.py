@@ -17,19 +17,15 @@ from functools import lru_cache
 import jax
 
 from repro.core.payload import clear_compile_log, compile_log
-from repro.obs import aggregate_snapshot
 from repro.session import CampaignSpec, ImpressSession, ProtocolSpec
 
 
 def write_bench_json(path: str, record: dict):
     """Persist a benchmark's machine-readable result record (the repo's
     perf trajectory across PRs — ``benchmarks/run.py`` emits
-    ``BENCH_scoring.json`` / ``BENCH_generate.json``). Every record embeds
-    the process-wide metrics summary (``obs.aggregate_snapshot``) so perf
-    numbers carry the telemetry of the run that produced them."""
+    ``BENCH_scoring.json`` / ``BENCH_generate.json``)."""
     record = dict(record, unix_time=time.time(),
-                  n_devices=len(jax.devices()),
-                  telemetry=aggregate_snapshot())
+                  n_devices=len(jax.devices()))
     with open(path, "w") as f:
         json.dump(record, f, indent=2, sort_keys=True)
         f.write("\n")

@@ -47,6 +47,7 @@ from typing import Callable, Dict, List, Optional, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core.api import Decision, DesignProtocol
 from repro.core.pipeline import Pipeline, Task, TaskState
 from repro.runtime.executor import AsyncExecutor
@@ -108,6 +109,10 @@ class Coordinator:
         # padded tokens), per kind; legacy exact-length dispatches are 1.0
         self._len_occupancy: List[float] = []
         self._gen_len_occupancy: List[float] = []
+        # seconds spent routing each drained completion (host clock), in
+        # the executor's registry beside the task series
+        self._handle_s = executor.telemetry.metrics.histogram(
+            "coordinator.handle_s")
         if protocol is not None:  # legacy single-protocol shim
             self.add_protocol(protocol, max_inflight=max_inflight)
 
@@ -401,7 +406,9 @@ class Coordinator:
         when the campaign pool is quiescent (no active pipeline, nothing
         inflight or buffered, trainer idle) — ``run`` stops there; a
         long-lived caller (the gateway's drive thread) keeps stepping, since
-        new campaigns may register at any time."""
+        new campaigns may register at any time. Routing a completion runs in
+        the ``impress.coordinator.handle`` program span and is timed into
+        ``coordinator.handle_s``."""
         if self.trainer is not None:
             self.trainer.tick()   # opportunistic model evolution
         active = any(p.active for p in self.pipelines.values())
@@ -428,9 +435,12 @@ class Coordinator:
             # the coordinator never enqueued — its original's CANCELED
             # completion owns the inflight decrement, so decrementing here
             # too would double-count and leave _inflight negative forever
-        self._handle(task)
-        self._pump()
-        self._drain_parked()
+        with obs.span("coordinator.handle"):
+            t0 = time.perf_counter()
+            self._handle(task)
+            self._pump()
+            self._drain_parked()
+            self._handle_s.observe(time.perf_counter() - t0)
         return True
 
     def run(self, timeout: float = 600.0) -> dict:
@@ -554,8 +564,9 @@ class Coordinator:
             "stages": (self.executor.stage_report()
                        if hasattr(self.executor, "stage_report") else {}),
             "quality_by_version": self._quality_by_version(pls),
-            # per-kind queue-wait/device-time quantiles, task counters, and
-            # span tallies from the unified telemetry layer (obs/)
+            # per-kind quantiles of queue wait and run time (``device_s``,
+            # host wall time of a member's run), task counters, and span
+            # tallies from the unified telemetry layer (obs/)
             "telemetry": (self.executor.telemetry_summary()
                           if hasattr(self.executor, "telemetry_summary")
                           else {}),

@@ -70,11 +70,21 @@ compile_log: Dict[str, list] = {"generate": [], "predict": []}
 # device fan-out — the occupancy numbers behind report()/benchmarks.
 batch_log: List[dict] = []
 
-# Same, for generate_batch dispatches.
+# Same, for generate_batch dispatches; a paged dispatch adds the decode
+# steps it ran and the sum over them of active slots (``steps``,
+# ``slot_steps``), beside its slot count (``bucket``).
 gen_batch_log: List[dict] = []
 
 # Same, for backbone_batch dispatches (the staged protocols' first stage).
 backbone_log: List[dict] = []
+
+
+def _named(fn, **kw):
+    """``partial(fn, **kw)`` under ``fn``'s name, so its jitted executable
+    reads ``jit_<fn>`` in a profile rather than ``jit__unknown``."""
+    p = partial(fn, **kw)
+    p.__name__ = fn.__name__
+    return p
 
 
 def _pad_rows(arrs: List[np.ndarray], rows: int):
@@ -333,8 +343,8 @@ class ProteinPayload:
                     axis=1)
             fn = self._compiled(
                 f"predict_mb1_L{Lb}{sfx}", dev,
-                lambda: jax.jit(partial(prot.foldscore_fwd_masked,
-                                        cfg=fcfg)))
+                lambda: jax.jit(_named(prot.foldscore_fwd_masked,
+                                       cfg=fcfg)))
             m = fn(fp, jax.device_put(seq, dev), jax.device_put(tgt, dev),
                    jax.device_put(np.asarray([true_len], np.int32), dev),
                    jax.device_put(np.asarray([split], np.int32), dev))
@@ -409,8 +419,8 @@ class ProteinPayload:
             if masked:
                 fn = self._compiled(
                     f"predict_mb{per}_L{L}{sfx}", dev,
-                    lambda: jax.jit(partial(prot.foldscore_fwd_masked,
-                                            cfg=fcfg)))
+                    lambda: jax.jit(_named(prot.foldscore_fwd_masked,
+                                           cfg=fcfg)))
                 futures.append(fn(fp, s, t,
                                   jax.device_put(seq_lens[sl], dev),
                                   jax.device_put(splits[sl], dev)))
@@ -688,6 +698,7 @@ class ProteinPayload:
             return out
 
         with eng.lock:
+            steps0, slot_steps0 = eng.steps, eng.slot_steps
             try:
                 res = eng.run(gp, temp,
                               specs=specs_for(bbs, seeds, row_lens, 0),
@@ -709,7 +720,9 @@ class ProteinPayload:
         batch = {"rows": R, "bucket": slots,
                  "occupancy": min(1.0, (R * n) / slots), "devices": 1,
                  "len_occupancy": tok_sum / float(R * length),
-                 "decode": "paged", "admitted": len(admitted)}
+                 "decode": "paged", "admitted": len(admitted),
+                 "steps": eng.steps - steps0,
+                 "slot_steps": eng.slot_steps - slot_steps0}
         gen_batch_log.append(batch)
         return {"rows": rows, "batch": dict(batch), "gen_version": ver}
 

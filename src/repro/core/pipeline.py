@@ -71,7 +71,7 @@ class Task:
     #   fn checks this between steps and returns early with resume state
     tenant: Optional[str] = None  # owning tenant (multi-tenant gateway):
     #   the coordinator's binding decorator stamps it, the executor slices
-    #   queue-wait/device-time metrics by it, and quota policies charge the
+    #   queue-wait/run-time metrics by it, and quota policies charge the
     #   dispatch leader's tenant for the devices a grant holds. None (the
     #   single-tenant scripts) changes nothing anywhere
     not_before: float = 0.0     # retry backoff: the scheduler skips this

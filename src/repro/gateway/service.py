@@ -78,7 +78,8 @@ from repro.core import Coordinator, ProteinPayload, ProtocolCrash
 from repro.data import protein_design_tasks
 from repro.resilience import maybe_corrupt
 from repro.gateway.quotas import QuotaManager, TenantQuota, tenant_band
-from repro.obs import Telemetry, Tracer, write_metrics, write_trace
+from repro.obs import (CompileWatcher, Telemetry, Tracer, write_metrics,
+                       write_trace)
 from repro.runtime import AsyncExecutor, DeviceAllocator
 from repro.runtime.allocator import LENGTH_BUCKETS, bucket_len
 from repro.session import (SCHEMA_VERSION, CampaignSpec, ProtocolSpec,
@@ -213,20 +214,24 @@ class GatewayService:
         return self
 
     def _drive(self):
-        while not self._stop.is_set():
-            with self._lock:
-                try:
-                    progressed = self.coordinator.step(drain_timeout=0.01)
-                except ProtocolCrash as crash:
-                    self._supervise(crash)
-                    progressed = True
-                self._refresh_states()
-                self._auto_checkpoint()
-                self._gc_campaigns()
-            if not progressed:
-                # quiescent: idle-wait off-lock so control ops never queue
-                # behind a sleeping drive thread
-                self._stop.wait(0.02)
+        # compiles and interpreter GC pauses land in the gateway's registry
+        with CompileWatcher(self.telemetry.metrics) as watcher:
+            while not self._stop.is_set():
+                with self._lock:
+                    try:
+                        progressed = self.coordinator.step(
+                            drain_timeout=0.01)
+                    except ProtocolCrash as crash:
+                        self._supervise(crash)
+                        progressed = True
+                    self._refresh_states()
+                    self._auto_checkpoint()
+                    self._gc_campaigns()
+                watcher.flush()
+                if not progressed:
+                    # quiescent: idle-wait off-lock so control ops never
+                    # queue behind a sleeping drive thread
+                    self._stop.wait(0.02)
 
     def _supervise(self, crash: ProtocolCrash):
         """A protocol handler crashed mid-route (lock held). Restart the

@@ -153,5 +153,7 @@ def paged_decode_bkgh(q, k_pages, v_pages, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
+        # the op name in a profile; bench/readers.py finds the kernel by it
+        name="_paged_decode_attention",
         **kwargs,
     )(block_tables, lengths, q, k_pages, v_pages)

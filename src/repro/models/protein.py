@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import lm as lm_mod
 from repro.models.common import dense_init, split_keys
 
@@ -150,6 +151,9 @@ class PagedDecodeEngine:
     without any shape change. ``trace_counts`` increments only when a
     jitted body is (re)traced: a warm engine admitting/retiring rows
     must keep it constant (the zero-recompile probe the tests assert).
+    ``steps`` and ``slot_steps`` count decode steps and, summed over them,
+    the slots active in each; ``step`` and ``_admit`` run inside the
+    ``impress.paged.step`` / ``impress.paged.admit`` program spans.
 
     Sampling streams are composition-independent: row token ``i`` is
     drawn with ``fold_in(base_key, i)`` where ``base_key`` rides in with
@@ -190,6 +194,8 @@ class PagedDecodeEngine:
         self._results = {}
         self.alloc_log = []                             # (tag, page ids)
         self.trace_counts = {"admit": 0, "step": 0}
+        self.steps = 0                                  # decode steps run
+        self.slot_steps = 0                             # sum of active slots
         # device state
         self.caches = self._put(lm_mod.init_paged_caches(
             cfg, self.n_pages + 1, self.page_size))
@@ -289,25 +295,27 @@ class PagedDecodeEngine:
         return sum(m is not None for m in self._slot_meta)
 
     def _admit(self, spec, params, temperature):
-        slot = self._slot_meta.index(None)
-        need = -(-(self.prompt_len + spec["length"] - 1) // self.page_size)
-        pages = [self.free_pages.pop() for _ in range(need)]
-        row = np.full(self.pages_per_row, self.trash_page, np.int32)
-        row[:need] = pages
-        self.block_tables[slot] = row
-        self.base_keys[slot] = spec["key"]
-        self.alloc_log.append((spec["tag"], tuple(pages)))
-        (self.caches, self.cur_tok, self.out_toks,
-         self.acc_lp) = self._admit_fn(
-            params, self._put(spec["backbone"][None]), self._put(row),
-            np.int32(slot), self._put(spec["key"]),
-            np.float32(temperature), self.caches, self.cur_tok,
-            self.out_toks, self.acc_lp)
-        self.true_lens[slot] = self.prompt_len
-        self._slot_meta[slot] = {"tag": spec["tag"],
-                                 "length": spec["length"], "done": 1}
-        if spec["length"] <= 1:
-            self._retire(slot)
+        with obs.span("paged.admit"):
+            slot = self._slot_meta.index(None)
+            need = -(-(self.prompt_len + spec["length"] - 1)
+                     // self.page_size)
+            pages = [self.free_pages.pop() for _ in range(need)]
+            row = np.full(self.pages_per_row, self.trash_page, np.int32)
+            row[:need] = pages
+            self.block_tables[slot] = row
+            self.base_keys[slot] = spec["key"]
+            self.alloc_log.append((spec["tag"], tuple(pages)))
+            (self.caches, self.cur_tok, self.out_toks,
+             self.acc_lp) = self._admit_fn(
+                params, self._put(spec["backbone"][None]), self._put(row),
+                np.int32(slot), self._put(spec["key"]),
+                np.float32(temperature), self.caches, self.cur_tok,
+                self.out_toks, self.acc_lp)
+            self.true_lens[slot] = self.prompt_len
+            self._slot_meta[slot] = {"tag": spec["tag"],
+                                     "length": spec["length"], "done": 1}
+            if spec["length"] <= 1:
+                self._retire(slot)
 
     def _retire(self, slot, out_host=None, lp_host=None):
         """Free a finished row's pages and record its result. ``out_host``
@@ -333,24 +341,30 @@ class PagedDecodeEngine:
 
     def step(self, params, temperature):
         """Advance every active slot one token; retire finished rows."""
-        (self.caches, self.cur_tok, self.out_toks,
-         self.acc_lp) = self._step_fn(
-            params, self.caches, self.cur_tok, self.out_toks, self.acc_lp,
-            self._put(self.block_tables), self._put(self.true_lens),
-            self._put(self.base_keys), np.float32(temperature))
-        finished = []
-        for slot, meta in enumerate(self._slot_meta):
-            if meta is None:
-                continue
-            self.true_lens[slot] += 1
-            meta["done"] += 1
-            if meta["done"] >= meta["length"]:
-                finished.append(slot)
-        if finished:
-            out_host = np.asarray(self.out_toks)
-            lp_host = np.asarray(self.acc_lp)
-            for slot in finished:
-                self._retire(slot, out_host, lp_host)
+        with obs.span("paged.step"):
+            (self.caches, self.cur_tok, self.out_toks,
+             self.acc_lp) = self._step_fn(
+                params, self.caches, self.cur_tok, self.out_toks,
+                self.acc_lp, self._put(self.block_tables),
+                self._put(self.true_lens), self._put(self.base_keys),
+                np.float32(temperature))
+            finished = []
+            active = 0
+            for slot, meta in enumerate(self._slot_meta):
+                if meta is None:
+                    continue
+                active += 1
+                self.true_lens[slot] += 1
+                meta["done"] += 1
+                if meta["done"] >= meta["length"]:
+                    finished.append(slot)
+            self.steps += 1
+            self.slot_steps += active
+            if finished:
+                out_host = np.asarray(self.out_toks)
+                lp_host = np.asarray(self.acc_lp)
+                for slot in finished:
+                    self._retire(slot, out_host, lp_host)
 
     def run(self, params, temperature, specs=(), poll=None):
         """Decode ``specs`` (plus anything ``poll`` injects) to completion.

@@ -1,33 +1,41 @@
-"""JAX compile/retrace watcher: first-class metric series for XLA
-compilation events.
+"""JAX compile watcher and interpreter GC pauses: first-class metric
+series for the two things that stall the host without the program asking.
 
-``CompileWatcher`` bridges two probe styles into the metrics registry:
+``CompileWatcher`` bridges these probes into the metrics registry:
 
 * ``jax.monitoring`` listeners: every trace/lower/compile duration event
   increments ``jax.compiles{event=...}`` and feeds
   ``jax.compile_s{event=...}`` (``backend_compile_duration`` is one XLA
   compile or persistent-cache load, ``cache_retrieval_time_sec`` one
   cache hit) — catching *every* trace/compile in the process, including
-  retraces the payload layer never sees. Listener registration is
-  process-global and cannot be undone, so one module-level listener fans
-  out to whichever watchers are currently active (the context manager
-  toggles an active flag instead of re-registering).
-* ``trace_counts``-style probes: explicit counters owned by long-lived
-  engines (e.g. ``PagedDecodeEngine.trace_counts``) — ``absorb_counts``
-  folds their deltas in under ``jax.traces{probe=..., event=...}``.
+  retraces the payload layer never sees.
+* ``gc.callbacks``: every collection of the interpreter's garbage
+  collector feeds ``gc.pause_s{generation=...}`` (seconds, host clock)
+  and is recorded as an ``impress.gc`` program span (``obs.span``) on
+  the profiler's clock, so a device profile shows which of the device's
+  idle gaps were GC pauses.
+
+Both hooks are process-global and cannot be undone, so one module-level
+listener of each kind fans out to whichever watchers are currently active
+(the context manager toggles an active flag instead of re-registering);
+with no watcher active a collection costs one list check.
 
 Payload-layer compile walls (``core.payload.compile_log``) are folded in
-the same way by the session at report time (``absorb_compile_log``).
+by the session at report time (``absorb_compile_log``).
 """
 
 from __future__ import annotations
 
+import gc
 import threading
+import time
 from typing import Dict, Optional
+
+from repro.obs.trace import span
 
 _lock = threading.Lock()
 _active: list = []            # active CompileWatcher instances
-_listener_installed = False
+_installed = False
 
 # jax.monitoring event substrings that mean "XLA compiled something"
 _COMPILE_MARKERS = ("compil", "trace", "lower")
@@ -42,46 +50,66 @@ def _on_event_duration(event: str, duration: float, **kw) -> None:
         w._record(event, duration)
 
 
-def _install_listener() -> None:
-    """Register the module-level jax.monitoring listener once."""
-    global _listener_installed
+# the collection in progress: (span, start). The interpreter runs one
+# collection at a time, its start and stop callbacks on one thread.
+_gc_open: Optional[tuple] = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook. A collection can start inside any allocation,
+    including one made while a metrics lock is held, so the hook takes no
+    lock: it queues the pause on each active watcher, and ``flush`` folds
+    the queue into the registry."""
+    global _gc_open
+    if phase == "start":
+        if _active:
+            sp = span("gc", generation=info["generation"])
+            sp.__enter__()
+            _gc_open = (sp, time.perf_counter())
+        return
+    if _gc_open is None:
+        return
+    sp, t0 = _gc_open
+    _gc_open = None
+    pause = time.perf_counter() - t0
+    sp.__exit__(None, None, None)
+    for w in list(_active):
+        w._pauses.append((info["generation"], pause))
+
+
+def _install_listeners() -> None:
+    """Register the module-level jax.monitoring listener and GC callback
+    once."""
+    global _installed
     with _lock:
-        if _listener_installed:
+        if _installed:
             return
         from jax import monitoring
         monitoring.register_event_duration_secs_listener(_on_event_duration)
-        _listener_installed = True
+        gc.callbacks.append(_on_gc)
+        _installed = True
 
 
 class CompileWatcher:
-    """Context manager streaming XLA compile events into a registry::
+    """Context manager streaming XLA compile events and GC pauses into a
+    registry::
 
         with CompileWatcher(registry):
-            ...  # jitted calls; compiles land in jax.compiles / jax.compile_s
+            ...  # compiles land in jax.compiles / jax.compile_s,
+                 # collections in gc.pause_s
 
     Inactive watchers cost nothing.
     """
 
     def __init__(self, registry):
         self.registry = registry
-        self._counts_seen: Dict[tuple, float] = {}
+        self._pauses: list = []       # (generation, seconds) not yet folded
 
     def _record(self, event: str, duration: float) -> None:
         short = event.rsplit("/", 1)[-1] or event
         self.registry.counter("jax.compiles", event=short).inc()
         self.registry.histogram("jax.compile_s",
                                 event=short).observe(float(duration))
-
-    def absorb_counts(self, probe: str, counts: Dict[str, int]) -> None:
-        """Fold a ``trace_counts``-style monotonically-growing counter dict
-        into the registry (delta since this watcher last saw the probe)."""
-        for name, n in counts.items():
-            key = (probe, name)
-            prev = self._counts_seen.get(key, 0)
-            if n > prev:
-                self.registry.counter("jax.traces", probe=probe,
-                                      event=name).inc(n - prev)
-                self._counts_seen[key] = n
 
     def absorb_compile_log(self, log: Dict[str, list],
                            start: Optional[Dict[str, int]] = None) -> None:
@@ -100,12 +128,23 @@ class CompileWatcher:
                     h.observe(float(w))
 
     def __enter__(self) -> "CompileWatcher":
-        _install_listener()
+        _install_listeners()
         with _lock:
             _active.append(self)
         return self
+
+    def flush(self) -> None:
+        """Fold the queued GC pauses into ``gc.pause_s{generation=...}``
+        (done on exit; a long-lived watcher calls it as it goes)."""
+        if not self._pauses:
+            return
+        pending, self._pauses = self._pauses, []
+        for gen, pause in pending:
+            self.registry.histogram("gc.pause_s",
+                                    generation=gen).observe(pause)
 
     def __exit__(self, *exc) -> None:
         with _lock:
             if self in _active:
                 _active.remove(self)
+        self.flush()

@@ -10,9 +10,8 @@ of magnitude below one jitted device dispatch.
 Histograms are *streaming*: p50/p95/max come from a sparse log-bucketed
 sketch (HDR-style, ~7% relative resolution at the default base) plus exact
 count/sum/min/max — no sample list is ever stored, so a million task
-completions cost the same memory as ten. Sketches merge associatively,
-which is what lets ``aggregate_snapshot`` fold every registry created in a
-process into one summary (the benchmark records embed that).
+completions cost the same memory as ten. Sketches merge associatively, so
+a reader can fold one series over several registries.
 
 Thread safety: every mutation takes the owning series' lock; get-or-create
 takes the registry lock. Reads (``snapshot``) are lock-consistent per
@@ -23,8 +22,7 @@ from __future__ import annotations
 
 import math
 import threading
-import weakref
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Tuple
 
 
 def _series_key(name: str, labels: dict) -> Tuple:
@@ -161,41 +159,6 @@ class Histogram:
                     "p50": p50, "p95": p95, "max": float(self.max)}
 
 
-_REGISTRIES: "weakref.WeakSet" = weakref.WeakSet()
-_RETIRED: Dict[Tuple, object] = {}    # merged series of GC'd registries
-_retired_lock = threading.Lock()
-
-
-def _merge_series(merged: Dict[Tuple, object], items) -> None:
-    """Fold ``(key, series)`` pairs into ``merged``: counters sum, gauges
-    keep the last value, histogram sketches merge."""
-    for k, s in items:
-        cur = merged.get(k)
-        if isinstance(s, Histogram):
-            if not isinstance(cur, Histogram):
-                cur = Histogram()
-                merged[k] = cur
-            cur.merge(s)
-        elif isinstance(s, Counter):
-            if not isinstance(cur, Counter):
-                cur = Counter()
-                merged[k] = cur
-            cur.inc(s.get())
-        else:
-            if not isinstance(cur, Gauge):
-                cur = Gauge()
-                merged[k] = cur
-            cur.set(s.get())
-
-
-def _retire(series: Dict[Tuple, object]) -> None:
-    """``weakref.finalize`` hook: when a registry is collected, its final
-    series fold into the retired accumulator, so ``aggregate_snapshot``
-    still reflects completed (shut-down) sessions."""
-    with _retired_lock:
-        _merge_series(_RETIRED, list(series.items()))
-
-
 class MetricsRegistry:
     """Get-or-create namespace of Counter/Gauge/Histogram series, each
     addressable by name plus optional labels::
@@ -208,8 +171,6 @@ class MetricsRegistry:
     def __init__(self):
         self._series: Dict[Tuple, object] = {}
         self._lock = threading.Lock()
-        _REGISTRIES.add(self)
-        weakref.finalize(self, _retire, self._series)
 
     def _get(self, cls, name: str, labels: dict):
         key = _series_key(name, labels)
@@ -266,21 +227,3 @@ class MetricsRegistry:
                                   else s.get())
         return out
 
-
-def aggregate_snapshot() -> dict:
-    """Merge every registry this process created — live ones plus the
-    retired accumulator of already-collected ones — into one flat
-    snapshot: counters sum, gauges keep their last value, histograms merge
-    sketches. The benchmark harness embeds this into each ``BENCH_*.json``
-    record so perf records carry the telemetry of the run that produced
-    them (including sessions already shut down)."""
-    merged: Dict[Tuple, object] = {}
-    with _retired_lock:
-        _merge_series(merged, list(_RETIRED.items()))
-    for reg in list(_REGISTRIES):
-        with reg._lock:
-            items = list(reg._series.items())
-        _merge_series(merged, items)
-    return {format_key(k): (s.summary() if isinstance(s, Histogram)
-                            else s.get())
-            for k, s in merged.items()}

@@ -28,6 +28,19 @@ dispatches share one timebase with the scheduler's fairness clock.
 A disabled tracer (``enabled=False``, the default for bare executors) turns
 every method into an early-out no-op — call sites stay unconditional and
 the untraced hot path pays one attribute load + one branch per event.
+
+Two clocks, two jobs:
+
+* lifecycle records and the metrics registry run on ``Telemetry.now``
+  (``time.monotonic`` unless injected) and export to the operator's
+  Perfetto JSON (``obs.export``);
+* scoped program spans (``span``), each a ``jax.profiler.TraceAnnotation``,
+  land on the profiler's host plane, on the same clock as the device's
+  operations, so a profile attributes the device's idle time to the
+  program layer that held the host. They are always in the code; with no
+  profiler running one costs what the annotation costs (under a
+  microsecond per enter and exit). Their names share the ``impress.``
+  namespace, which no runtime event uses.
 """
 
 from __future__ import annotations
@@ -37,9 +50,24 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # canonical lifecycle event names (the export's span phases)
 LIFECYCLE = ("submitted", "queued", "granted", "dispatched", "completed",
              "failed", "canceled", "preempted", "retried")
+
+
+SPAN_PREFIX = "impress."
+
+
+def span(name: str, **metadata: Any) -> TraceAnnotation:
+    """A scoped program span ``impress.<name>`` on the profiler's clock::
+
+        with span("paged.step"):
+            ...
+
+    ``metadata`` (e.g. ``kind=...``) rides on the profiler event."""
+    return TraceAnnotation(SPAN_PREFIX + name, **metadata)
 
 
 class Tracer:

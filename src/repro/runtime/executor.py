@@ -70,6 +70,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from repro.core.pipeline import TERMINAL, Task, TaskState
+from repro import obs
 from repro.obs import Telemetry
 from repro.resilience.deadletter import DeadLetterQueue
 from repro.resilience.policy import ResilienceManager, RetryPolicy
@@ -520,51 +521,58 @@ class AsyncExecutor:
                     # deterministic chaos seam: may sleep (slowdown), kill
                     # a device, or raise a classified payload error
                     self.fault_plan.on_dispatch(task, members, self)
-                result = fn(sub, payload)
-                if port is not None and port.admitted:
-                    # live-admitted rows follow the initial members' rows
-                    # in the fused result — same fan-out as dequeue-time
-                    members = members + port.admitted
-                results = (rule.split(members, result)
-                           if len(members) > 1 else [result])
-                now = self.now()
-                for m, r in zip(members, results):
-                    if getattr(m, "_deadline_exceeded", False):
-                        # the watchdog flagged this run as over its
-                        # deadline: fail it (class "deadline") so the
-                        # owning pipeline degrades instead of wedging on
-                        # a CANCELED completion
-                        m.error = (f"DeadlineExceeded: ran past the "
-                                   f"policy deadline "
-                                   f"({self.resilience.policy.deadline_s}s)")
-                        m.set_state(TaskState.FAILED, now)
-                        tel.tracer.mark(m, "failed")
-                        tel.metrics.counter("tasks.failed",
-                                            kind=m.kind).inc()
-                        tel.metrics.counter(
-                            "tasks.failed", **{"class": "deadline"}).inc()
-                        self.deadletter.record(m, error_class="deadline",
-                                               error=m.error, now=now)
-                    elif m.canceled:
-                        m.set_state(TaskState.CANCELED, now)
-                        tel.tracer.mark(m, "canceled")
-                        tel.metrics.counter("tasks.canceled",
-                                            kind=m.kind).inc()
-                    else:
-                        m.result = r
-                        m.set_state(TaskState.DONE, now)
-                        tel.tracer.mark(m, "completed")
-                        self.resilience.on_success(m)
-                        self._observe_done(m)
-                        d = m.duration()
-                        if d is not None:
-                            self._durations.setdefault(m.kind, []).append(d)
-                    finished.append(m)
-                tel.tracer.dispatch_end(
-                    span, "ok", rows=(sum(rule.rows(m) for m in members)
-                                      if rule is not None else len(members)))
-                self._record_stage(task, members, rule)
-                self._record_tenants(members)
+                # program span on the profiler's clock: the payload run
+                # and the fan-out of its result
+                with obs.span("executor.dispatch", kind=task.kind):
+                    result = fn(sub, payload)
+                    if port is not None and port.admitted:
+                        # live-admitted rows follow the initial members'
+                        # rows in the fused result — same fan-out as
+                        # dequeue-time
+                        members = members + port.admitted
+                    results = (rule.split(members, result)
+                               if len(members) > 1 else [result])
+                    now = self.now()
+                    for m, r in zip(members, results):
+                        if getattr(m, "_deadline_exceeded", False):
+                            # the watchdog flagged this run as over its
+                            # deadline: fail it (class "deadline") so the
+                            # owning pipeline degrades instead of wedging
+                            # on a CANCELED completion
+                            m.error = (
+                                f"DeadlineExceeded: ran past the policy "
+                                f"deadline "
+                                f"({self.resilience.policy.deadline_s}s)")
+                            m.set_state(TaskState.FAILED, now)
+                            tel.tracer.mark(m, "failed")
+                            tel.metrics.counter("tasks.failed",
+                                                kind=m.kind).inc()
+                            tel.metrics.counter(
+                                "tasks.failed", **{"class": "deadline"}).inc()
+                            self.deadletter.record(m, error_class="deadline",
+                                                   error=m.error, now=now)
+                        elif m.canceled:
+                            m.set_state(TaskState.CANCELED, now)
+                            tel.tracer.mark(m, "canceled")
+                            tel.metrics.counter("tasks.canceled",
+                                                kind=m.kind).inc()
+                        else:
+                            m.result = r
+                            m.set_state(TaskState.DONE, now)
+                            tel.tracer.mark(m, "completed")
+                            self.resilience.on_success(m)
+                            self._observe_done(m)
+                            d = m.duration()
+                            if d is not None:
+                                self._durations.setdefault(
+                                    m.kind, []).append(d)
+                        finished.append(m)
+                    tel.tracer.dispatch_end(
+                        span, "ok",
+                        rows=(sum(rule.rows(m) for m in members)
+                              if rule is not None else len(members)))
+                    self._record_stage(task, members, rule)
+                    self._record_tenants(members)
             except Exception as e:  # noqa: BLE001 — any payload failure
                 if port is not None and port.admitted \
                         and port.admitted[-1] is not members[-1]:
@@ -635,8 +643,11 @@ class AsyncExecutor:
 
     def _observe_done(self, m: Task):
         """Per-kind completion series: queue wait (RUNNING − QUEUED) and
-        device time, as streaming histograms — the p50/p95 behind
-        ``report()["telemetry"]``."""
+        run time, as streaming histograms — the p50/p95 behind
+        ``report()["telemetry"]``. ``task.device_s`` is the host wall time
+        of the member's run (RUNNING to DONE on the executor's clock: the
+        payload call and its fan-out), not device time; the name stays
+        because readers read the series by it."""
         metrics = self.telemetry.metrics
         metrics.counter("tasks.completed", kind=m.kind).inc()
         q = m.timestamps.get("QUEUED")
@@ -930,8 +941,9 @@ class AsyncExecutor:
 
     def telemetry_summary(self) -> dict:
         """The new observability section for ``report()["telemetry"]``:
-        per-kind queue-wait / device-time quantile summaries, task
-        counters, and span counts when tracing is on."""
+        per-kind quantile summaries of queue wait and of each member's
+        run (``device_s``: host wall time of the run, not device time),
+        task counters, and span counts when tracing is on."""
         m = self.telemetry.metrics
         kinds: Dict[str, dict] = {}
         for kind, h in m.labeled("task.queue_wait_s", "kind").items():

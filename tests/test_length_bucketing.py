@@ -152,6 +152,31 @@ def test_predict_batch_masked_matches_per_row_predict(payload, submesh):
             assert out["rows"][i][k] == pytest.approx(solo[k], abs=ATOL)
 
 
+def test_masked_scorer_executable_is_named(payload, submesh):
+    """The masked scorer's executables, batched and solo, lower to a module
+    named after ``foldscore_fwd_masked`` (not ``jit__unknown``): the name
+    a profile shows them by."""
+    rng = np.random.default_rng(6)
+    lens = [10, 13, 16, 16]
+    seqs, rows = mixed_rows(rng, lens, 16)
+    tgt = rng.normal(size=(4, 16)).astype(np.float32)
+    payload.predict_batch(submesh, {
+        "sequences": seqs, "target": tgt, "receptor_len": 6,
+        "seq_lens": np.array(lens, np.int32)})
+    payload.predict(submesh, {"sequence": rows[0], "target": tgt[0],
+                              "receptor_len": 6, "seq_len": lens[0]})
+    fns = {key[0]: fn for key, fn in payload._cache.items()
+           if str(key[0]).startswith("predict_mb")}
+    assert "predict_mb4_L16" in fns
+    assert any(k.startswith("predict_mb1_L") for k in fns)
+    assert {fn.__name__ for fn in fns.values()} == {"foldscore_fwd_masked"}
+    lowered = fns["predict_mb4_L16"].lower(
+        payload.fold_params, seqs, tgt, np.array(lens, np.int32),
+        np.full(4, 6, np.int32))
+    assert lowered.as_text().startswith(
+        "module @jit_foldscore_fwd_masked ")
+
+
 def test_predict_batch_legacy_has_no_len_padding(payload, submesh):
     """Without seq_lens the payload takes the exact path (len_occupancy 1,
     chain_split static) — homogeneous campaigns stay on seed behavior."""

@@ -3,6 +3,8 @@ lifecycle tracing through the live executor, Perfetto export validation,
 fake-clock deterministic timing, and the golden report-section schemas
 that must survive the registry rebuild."""
 
+import gc
+import glob
 import json
 import threading
 import time
@@ -11,9 +13,8 @@ import numpy as np
 import pytest
 
 from repro.core.pipeline import ResourceRequest, Task, TaskState
-from repro.obs import (MetricsRegistry, Telemetry, Tracer,
-                       aggregate_snapshot, validate_trace, write_metrics,
-                       write_trace)
+from repro.obs import (CompileWatcher, MetricsRegistry, Telemetry, Tracer,
+                       span, validate_trace, write_metrics, write_trace)
 from repro.obs.metrics import Histogram
 from repro.runtime import AsyncExecutor, DeviceAllocator
 from repro.runtime.executor import CoalesceRule
@@ -94,16 +95,98 @@ def test_registry_series_labels_and_snapshot():
         reg.gauge("tasks.completed", kind="predict")
 
 
-def test_aggregate_snapshot_merges_registries():
-    a, b = MetricsRegistry(), MetricsRegistry()
-    a.counter("agg.test_counter").inc(2)
-    b.counter("agg.test_counter").inc(5)
-    a.histogram("agg.test_hist").observe(1.0)
-    b.histogram("agg.test_hist").observe(3.0)
-    merged = aggregate_snapshot()
-    assert merged["agg.test_counter"] == 7
-    assert merged["agg.test_hist"]["count"] == 2
-    assert merged["agg.test_hist"]["max"] == 3.0
+# ---------------------------------------------------------------------------
+# program spans on the profiler's clock, GC pauses
+# ---------------------------------------------------------------------------
+
+def _profiled_spans(trace_dir):
+    """``impress.*`` events of the host planes of the profile under
+    ``trace_dir``: [(name, start_ns, end_ns, stats)]."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("impress."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return out
+
+
+def test_span_lands_on_profiler_host_plane_nested(tmp_path):
+    import jax
+    with jax.profiler.trace(str(tmp_path)):
+        with span("outer", kind="generate_batch"):
+            with span("inner"):
+                time.sleep(0.002)
+    got = {name: (s, e, stats)
+           for name, s, e, stats in _profiled_spans(tmp_path)}
+    assert set(got) == {"impress.outer", "impress.inner"}
+    (os_, oe, ostats), (is_, ie, _) = got["impress.outer"], \
+        got["impress.inner"]
+    assert os_ <= is_ < ie <= oe            # nested as entered
+    assert ie - is_ >= 2e6
+    assert ostats["kind"] == "generate_batch"
+
+
+def test_executor_dispatch_span_carries_kind(tmp_path):
+    import jax
+    ex, _ = traced_executor()
+    ex.register("noop", lambda sm, p: p["x"])
+    with jax.profiler.trace(str(tmp_path)):
+        ex.submit(Task(kind="noop", payload={"x": 1}))
+        done = ex.drain(timeout=10)
+    ex.shutdown()
+    assert done is not None and done.state == TaskState.DONE
+    disp = [(s, e, st) for name, s, e, st in _profiled_spans(tmp_path)
+            if name == "impress.executor.dispatch"]
+    assert len(disp) == 1 and disp[0][2]["kind"] == "noop"
+
+
+def test_gc_pause_recorded_while_watching():
+    reg = MetricsRegistry()
+    with CompileWatcher(reg):
+        gc.collect()
+    full = reg.histogram("gc.pause_s", generation=2)
+    assert full.count >= 1 and full.sum > 0
+    n = full.count
+    gc.collect()                            # no watcher of this registry
+    assert full.count == n
+
+
+def test_gc_pause_recorded_as_span(tmp_path):
+    import jax
+    with CompileWatcher(MetricsRegistry()):
+        with jax.profiler.trace(str(tmp_path)):
+            gc.collect()
+    pauses = [st for name, _, _, st in _profiled_spans(tmp_path)
+              if name == "impress.gc"]
+    assert any(st.get("generation") == 2 for st in pauses)
+
+
+def test_gc_hook_takes_no_metrics_lock():
+    """A collection can start while this thread holds a metrics lock: the
+    hook queues the pause instead of taking the lock, so nothing
+    deadlocks, and the pause lands in the registry on exit."""
+    reg = MetricsRegistry()
+    done = threading.Event()
+
+    def body():
+        with CompileWatcher(reg):
+            h = reg.histogram("gc.pause_s", generation=2)
+            with reg._lock, h._lock:
+                gc.collect()
+        done.set()
+
+    th = threading.Thread(target=body, daemon=True)
+    th.start()
+    th.join(10)
+    assert done.is_set()
+    assert reg.histogram("gc.pause_s", generation=2).count >= 1
 
 
 # ---------------------------------------------------------------------------

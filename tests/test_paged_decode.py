@@ -200,6 +200,41 @@ def test_engine_composition_independence_zero_recompiles():
     assert eng.trace_counts == {"admit": 1, "step": 1}
 
 
+def test_engine_counts_steps_and_slot_steps():
+    """``steps`` / ``slot_steps`` against arithmetic on the rows' lengths:
+    admission samples a row's first token, so a row of length L is active
+    for L - 1 steps. Rows of 3, 6 and 1 start together (the last retires
+    at admission); a row of 7 is poll-injected before the third step, so
+    the run takes 2 + 6 steps."""
+    eng = prot.PagedDecodeEngine(CFG, slots=3, max_new=7, interpret=True)
+    specs = [dict(s, length=n) for s, n in zip(_specs(4, 7), (3, 6, 1, 7))]
+    calls = []
+
+    def poll(free):
+        calls.append(free)
+        return [specs[3]] if len(calls) == 3 else []
+
+    eng.run(PARAMS, 1.0, specs[:3], poll=poll)
+    assert eng.slot_steps == (3 - 1) + (6 - 1) + 0 + (7 - 1)
+    assert eng.steps == 2 + (7 - 1)
+    eng.run(PARAMS, 1.0, [dict(specs[0], tag=9)])     # counts accumulate
+    assert (eng.steps, eng.slot_steps) == (8 + 2, 13 + 2)
+
+
+def test_gen_batch_log_carries_step_deltas():
+    """Each paged dispatch's log record holds its own steps and active
+    slot-steps (deltas of the engine's running counts), beside its slot
+    count: 1 row x 2 candidates of 6 tokens take 5 steps in 4 slots."""
+    pp = ProteinPayload(jax.random.PRNGKey(0), reduced=True, length=6)
+    mesh = _Mesh()
+    at = len(gen_batch_log)
+    pp.generate_batch(mesh, _gen_payload(0))
+    pp.generate_batch(mesh, _gen_payload(1))
+    for rec in gen_batch_log[at:]:
+        assert rec["bucket"] == 4
+        assert (rec["steps"], rec["slot_steps"]) == (5, 2 * 5)
+
+
 # ---------------------------------------------------------------------------
 # payload + executor: paged dispatch and live admission
 # ---------------------------------------------------------------------------

@@ -165,6 +165,30 @@ def test_custom_protocol_runs_through_unmodified_coordinator():
     assert rep["protocols"]["take-first"]["n_pipelines"] == 1
 
 
+def test_coordinator_handle_s_counts_completions():
+    """``coordinator.handle_s``, in the executor's registry, observes once
+    for every completion the coordinator routes."""
+    ex = fake_executor(seed=5)
+    proto = impress(seed=5, n_cycles=2, n_candidates=3)
+    coord = Coordinator(ex)
+    coord.add_protocol(proto)
+    routed = []
+    handle = coord._handle
+
+    def counted(task):
+        routed.append(task.uid)
+        return handle(task)
+
+    coord._handle = counted
+    for i in range(2):
+        coord.add_pipeline(new_pl(proto, f"H{i}"))
+    coord.run(timeout=60)
+    ex.shutdown()
+    h = ex.telemetry.metrics.histogram("coordinator.handle_s")
+    assert len(routed) >= 4 and h.count == len(routed)
+    assert h.sum > 0
+
+
 def test_multi_objective_pareto_rule():
     assert dominates([2, 2, 2], [1, 2, 2])
     assert not dominates([1, 2, 2], [2, 2, 2])

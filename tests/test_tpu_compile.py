@@ -79,6 +79,27 @@ def test_paged_decode_step_compiles(one_chip, page_size):
     assert eng.trace_counts["step"] == 1
 
 
+def test_paged_kernel_op_name(one_chip):
+    """The Pallas kernel's own ``name=`` names its op in the compiled
+    program, whatever function wraps it: the benchmark's trace readers
+    find the kernel by ``_paged_decode_attention``."""
+    from repro.kernels import paged_attention as pa
+    B, KV, G, hd, page, maxp, P = 4, 2, 2, 32, 8, 3, 13
+    f = jax.jit(lambda q, k, v, bt, ln: pa.paged_decode_bkgh(
+        q, k, v, bt, ln, page_size=page, interpret=False))
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+    text = f.lower(spec((B, KV, G, hd), jnp.bfloat16),
+                   spec((P, KV, page, hd), jnp.bfloat16),
+                   spec((P, KV, page, hd), jnp.bfloat16),
+                   spec((B, maxp), jnp.int32),
+                   spec((B,), jnp.int32)).compile().as_text()
+    ops = [line.split(" = ", 1)[0].split()[-1] for line in text.splitlines()
+           if "custom-call(" in line and "tpu_custom_call" in line]
+    assert ops and all(o.startswith("%_paged_decode_attention")
+                       for o in ops), ops
+
+
 def test_masked_predict_compiles(one_chip):
     """``foldscore_fwd_masked`` at ``foldscore-m`` widths: 8 rows padded
     to the 144-token bucket, per-row lengths and chain splits traced."""
