@@ -195,7 +195,7 @@ def _timed(eng, params, specs):
         eng.submit(**s)
     t0 = time.perf_counter()
     eng._pump(params, 1.0)
-    jax.block_until_ready(eng.caches)
+    jax.block_until_ready(eng.state)
     t_admit = time.perf_counter() - t0
     t0 = time.perf_counter()
     while eng.active_slots():

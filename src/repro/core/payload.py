@@ -71,8 +71,9 @@ compile_log: Dict[str, list] = {"generate": [], "predict": []}
 batch_log: List[dict] = []
 
 # Same, for generate_batch dispatches; a paged dispatch adds the decode
-# steps it ran and the sum over them of active slots (``steps``,
-# ``slot_steps``), beside its slot count (``bucket``).
+# steps it ran, the sum over them of active slots and the host time they
+# took (``steps``, ``slot_steps``, ``step_host_s``), beside its slot count
+# (``bucket``).
 gen_batch_log: List[dict] = []
 
 # Same, for backbone_batch dispatches (the staged protocols' first stage).
@@ -699,6 +700,7 @@ class ProteinPayload:
 
         with eng.lock:
             steps0, slot_steps0 = eng.steps, eng.slot_steps
+            step_s0 = eng.step_host_s
             try:
                 res = eng.run(gp, temp,
                               specs=specs_for(bbs, seeds, row_lens, 0),
@@ -722,7 +724,8 @@ class ProteinPayload:
                  "len_occupancy": tok_sum / float(R * length),
                  "decode": "paged", "admitted": len(admitted),
                  "steps": eng.steps - steps0,
-                 "slot_steps": eng.slot_steps - slot_steps0}
+                 "slot_steps": eng.slot_steps - slot_steps0,
+                 "step_host_s": eng.step_host_s - step_s0}
         gen_batch_log.append(batch)
         return {"rows": rows, "batch": dict(batch), "gen_version": ver}
 

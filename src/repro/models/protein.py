@@ -17,6 +17,7 @@ FoldScore — AlphaFold analogue: predicts structure-confidence metrics for a
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, NamedTuple
 
 import jax
@@ -141,19 +142,28 @@ class PagedDecodeEngine:
     """Continuous-batching ProGen sampler over a paged KV cache.
 
     A fixed number of decode *slots* share one pool of fixed-size K/V
-    pages (``lm.init_paged_caches``); per-slot block tables and true
-    lengths live host-side. Admission prefils one row's prompt into
-    freshly popped pages (a fixed (1, S0) executable) and samples its
-    first token; every step advances all active slots through one fused
-    ``lm.paged_decode_step``; retirement reads the finished row out,
-    returns its pages to a LIFO free pool and zeroes its true length —
-    so rows of different lengths enter and leave a *running* batch
-    without any shape change. ``trace_counts`` increments only when a
-    jitted body is (re)traced: a warm engine admitting/retiring rows
-    must keep it constant (the zero-recompile probe the tests assert).
-    ``steps`` and ``slot_steps`` count decode steps and, summed over them,
-    the slots active in each; ``step`` and ``_admit`` run inside the
-    ``impress.paged.step`` / ``impress.paged.admit`` program spans.
+    pages (``lm.init_paged_caches``). Every per-slot array (block tables,
+    true lengths, end lengths, base keys, current and sampled tokens,
+    log-likelihoods) lives in ``state`` on the device, donated to and
+    rebound from each jitted call. Admission prefils one row's prompt into
+    freshly popped pages (a fixed (1, S0) executable), samples its first
+    token and writes the slot's row of that state; every step advances all
+    active slots through one fused ``lm.paged_decode_step`` and, on the
+    device, retires a slot that reached its end length (true length 0,
+    block-table row to the trash page). A step takes nothing from the
+    host. The host keeps numpy mirrors (``block_tables``, ``true_lens``,
+    ``base_keys``, ``_slot_meta``) updated by the same arithmetic, never
+    read back: they decide pages, counts and retirement, whose single
+    read of the finished rows out of ``state`` returns their pages to a
+    LIFO free pool — so rows of different lengths enter and leave a
+    *running* batch without any shape change. ``trace_counts``
+    increments only when a jitted body is (re)traced: a warm engine
+    admitting/retiring rows must keep it constant (the zero-recompile
+    probe the tests assert). ``steps`` and ``slot_steps`` count decode
+    steps and, summed over them, the slots active in each;
+    ``step_host_s`` sums the host wall time spent in ``step``. ``step``
+    and ``_admit`` run inside the ``impress.paged.step`` /
+    ``impress.paged.admit`` program spans.
 
     Sampling streams are composition-independent: row token ``i`` is
     drawn with ``fold_in(base_key, i)`` where ``base_key`` rides in with
@@ -179,11 +189,11 @@ class PagedDecodeEngine:
         self.lock = threading.Lock()                    # one run at a time
         # device_put of a numpy array can be zero-copy on CPU, so the
         # async-dispatched computation would alias host buffers we mutate
-        # in place (block_tables, true_lens) — always hand jax a copy.
+        # in place (the mirrors below) — always hand jax a copy.
         self._put = lambda x: jax.device_put(
             jax.tree.map(lambda a: a.copy() if isinstance(a, np.ndarray)
                          else a, x), device)
-        # host bookkeeping
+        # host bookkeeping and mirrors of the device's per-slot state
         self.free_pages = list(range(self.n_pages))     # LIFO pool
         self.block_tables = np.full((self.slots, self.pages_per_row),
                                     self.trash_page, np.int32)
@@ -196,67 +206,79 @@ class PagedDecodeEngine:
         self.trace_counts = {"admit": 0, "step": 0}
         self.steps = 0                                  # decode steps run
         self.slot_steps = 0                             # sum of active slots
-        # device state
-        self.caches = self._put(lm_mod.init_paged_caches(
-            cfg, self.n_pages + 1, self.page_size))
-        self.cur_tok = self._put(np.zeros((self.slots, 1), np.int32))
-        self.out_toks = self._put(np.zeros((self.slots, self.max_new),
-                                           np.int32))
-        self.acc_lp = self._put(np.zeros(self.slots, np.float32))
-        # donate the engine-owned state (caches + per-slot arrays): the
-        # update is in-place on device instead of copying the whole page
-        # pool every admit/step — the copies would grow with slots and
-        # dominate the step at wide batches. The engine always rebinds
-        # self.* from the outputs, so the consumed buffers are never read.
-        self._admit_fn = jax.jit(self._build_admit(),
-                                 donate_argnums=(6, 7, 8, 9))
-        self._step_fn = jax.jit(self._build_step(),
-                                donate_argnums=(1, 2, 3, 4))
+        self.step_host_s = 0.0                          # host time in step()
+        self._temp = (None, None)                       # (value, on device)
+        # device state, donated to every admit/step: the update is
+        # in-place on device instead of copying the whole page pool — the
+        # copies would grow with slots and dominate the step at wide
+        # batches. The engine always rebinds self.state from the outputs,
+        # so the consumed buffers are never read.
+        self.state = self._put({
+            "caches": lm_mod.init_paged_caches(cfg, self.n_pages + 1,
+                                               self.page_size),
+            "cur_tok": np.zeros((self.slots, 1), np.int32),
+            "out_toks": np.zeros((self.slots, self.max_new), np.int32),
+            "acc_lp": np.zeros(self.slots, np.float32),
+            "block_tables": self.block_tables,
+            "true_lens": self.true_lens,
+            "end_lens": np.zeros(self.slots, np.int32),
+            "base_keys": self.base_keys})
+        self._admit_fn = jax.jit(self._build_admit(), donate_argnums=(7,))
+        self._step_fn = jax.jit(self._build_step(), donate_argnums=(1,))
 
     # -- jitted bodies ---------------------------------------------------
 
     def _build_admit(self):
-        cfg, S0 = self.cfg, self.prompt_len
+        cfg, S0, trash = self.cfg, self.prompt_len, self.trash_page
 
-        def fn(params, backbone, bt_row, slot, base_key, temp,
-               caches, cur_tok, out_toks, acc_lp):
+        def fn(params, backbone, bt_row, slot, base_key, end_len, temp,
+               state):
             self.trace_counts["admit"] += 1     # traces only on compile
             patches = encode_structure(params, backbone, cfg)
             bos = jnp.zeros((1, 1), jnp.int32)
             logits, caches = lm_mod.paged_prefill(
-                params, {"inputs": bos, "patches": patches}, cfg, caches,
-                bt_row[None])
+                params, {"inputs": bos, "patches": patches}, cfg,
+                state["caches"], bt_row[None])
             logits = logits.astype(jnp.float32).at[:, cfg.vocab_size:].set(
                 -1e30)
             k0 = jax.random.fold_in(base_key, 0)
             tok0 = jax.random.categorical(k0, logits / temp, axis=-1)
             lp0 = jnp.take_along_axis(jax.nn.log_softmax(logits, -1),
                                       tok0[:, None], -1)[0, 0]
-            cur_tok = cur_tok.at[slot, 0].set(tok0[0])
-            row = jnp.zeros((out_toks.shape[1],), jnp.int32).at[0].set(
-                tok0[0])
-            out_toks = out_toks.at[slot].set(row)
-            acc_lp = acc_lp.at[slot].set(lp0)
-            return caches, cur_tok, out_toks, acc_lp
+            row = jnp.zeros((state["out_toks"].shape[1],), jnp.int32).at[
+                0].set(tok0[0])
+            live = end_len > S0         # a one-token row retires at once
+            return dict(
+                caches=caches,
+                cur_tok=state["cur_tok"].at[slot, 0].set(tok0[0]),
+                out_toks=state["out_toks"].at[slot].set(row),
+                acc_lp=state["acc_lp"].at[slot].set(lp0),
+                block_tables=state["block_tables"].at[slot].set(
+                    jnp.where(live, bt_row, trash)),
+                true_lens=state["true_lens"].at[slot].set(
+                    jnp.where(live, S0, 0)),
+                end_lens=state["end_lens"].at[slot].set(end_len),
+                base_keys=state["base_keys"].at[slot].set(base_key))
 
         return fn
 
     def _build_step(self):
-        cfg, S0 = self.cfg, self.prompt_len
+        cfg, S0, trash = self.cfg, self.prompt_len, self.trash_page
         interpret = self.interpret
 
-        def fn(params, caches, cur_tok, out_toks, acc_lp, block_tables,
-               true_lens, base_keys, temp):
+        def fn(params, state, temp):
             self.trace_counts["step"] += 1      # traces only on compile
+            true_lens, block_tables = state["true_lens"], state["block_tables"]
+            out_toks = state["out_toks"]
             active = true_lens > 0
             lengths = jnp.where(active, true_lens + 1, 0)
             logits, caches = lm_mod.paged_decode_step(
-                params, caches, cur_tok, true_lens, block_tables, lengths,
-                cfg, interpret=interpret)
+                params, state["caches"], state["cur_tok"], true_lens,
+                block_tables, lengths, cfg, interpret=interpret)
             logits = logits.astype(jnp.float32).at[:, cfg.vocab_size:].set(
                 -1e30)
             idx = true_lens - S0 + 1            # tokens sampled so far
-            keys = jax.vmap(jax.random.fold_in)(base_keys, idx)
+            keys = jax.vmap(jax.random.fold_in)(state["base_keys"], idx)
             nxt = jax.vmap(
                 lambda k, lg: jax.random.categorical(k, lg / temp))(
                     keys, logits)
@@ -265,11 +287,20 @@ class PagedDecodeEngine:
             rows = jnp.arange(nxt.shape[0])
             col = jnp.clip(idx, 0, out_toks.shape[1] - 1)
             keep = out_toks[rows, col]
-            out_toks = out_toks.at[rows, col].set(
-                jnp.where(active, nxt, keep))
-            acc_lp = acc_lp + jnp.where(active, step_lp, 0.0)
-            cur_tok = jnp.where(active[:, None], nxt[:, None], cur_tok)
-            return caches, cur_tok, out_toks, acc_lp
+            # a slot that reached its end length retires here, as the
+            # host's mirror does: length 0 and the trash page, because an
+            # inactive slot still writes K/V through its row, and a stale
+            # row would write into pages another slot has taken
+            done = active & (lengths >= state["end_lens"])
+            return dict(
+                state, caches=caches,
+                cur_tok=jnp.where(active[:, None], nxt[:, None],
+                                  state["cur_tok"]),
+                out_toks=out_toks.at[rows, col].set(
+                    jnp.where(active, nxt, keep)),
+                acc_lp=state["acc_lp"] + jnp.where(active, step_lp, 0.0),
+                true_lens=jnp.where(done, 0, lengths),
+                block_tables=jnp.where(done[:, None], trash, block_tables))
 
         return fn
 
@@ -294,6 +325,14 @@ class PagedDecodeEngine:
     def active_slots(self) -> int:
         return sum(m is not None for m in self._slot_meta)
 
+    def _device_temp(self, temperature):
+        """The sampling temperature as a device scalar, transferred only
+        when its value changes: a run's steps and admits reuse one."""
+        t = float(temperature)
+        if self._temp[0] != t:
+            self._temp = (t, self._put(np.float32(t)))
+        return self._temp[1]
+
     def _admit(self, spec, params, temperature):
         with obs.span("paged.admit"):
             slot = self._slot_meta.index(None)
@@ -305,12 +344,11 @@ class PagedDecodeEngine:
             self.block_tables[slot] = row
             self.base_keys[slot] = spec["key"]
             self.alloc_log.append((spec["tag"], tuple(pages)))
-            (self.caches, self.cur_tok, self.out_toks,
-             self.acc_lp) = self._admit_fn(
+            self.state = self._admit_fn(
                 params, self._put(spec["backbone"][None]), self._put(row),
                 np.int32(slot), self._put(spec["key"]),
-                np.float32(temperature), self.caches, self.cur_tok,
-                self.out_toks, self.acc_lp)
+                np.int32(self.prompt_len + spec["length"] - 1),
+                self._device_temp(temperature), self.state)
             self.true_lens[slot] = self.prompt_len
             self._slot_meta[slot] = {"tag": spec["tag"],
                                      "length": spec["length"], "done": 1}
@@ -318,13 +356,14 @@ class PagedDecodeEngine:
                 self._retire(slot)
 
     def _retire(self, slot, out_host=None, lp_host=None):
-        """Free a finished row's pages and record its result. ``out_host``
-        / ``lp_host`` are optional host snapshots of out_toks / acc_lp so
-        a step retiring many rows pays one device->host read, not 2/row."""
+        """Free a finished row's pages and record its result; the device
+        has already retired the slot. ``out_host`` / ``lp_host`` are
+        optional host snapshots of out_toks / acc_lp so a step retiring
+        many rows pays one device->host read, not 2/row."""
         meta = self._slot_meta[slot]
         if out_host is None:
-            out_host = np.asarray(self.out_toks)
-            lp_host = np.asarray(self.acc_lp)
+            out_host = np.asarray(self.state["out_toks"])
+            lp_host = np.asarray(self.state["acc_lp"])
         toks = np.asarray(out_host[slot, :meta["length"]], np.int32)
         ll = float(lp_host[slot])
         for pid in self.block_tables[slot]:
@@ -341,13 +380,10 @@ class PagedDecodeEngine:
 
     def step(self, params, temperature):
         """Advance every active slot one token; retire finished rows."""
+        t0 = time.perf_counter()
         with obs.span("paged.step"):
-            (self.caches, self.cur_tok, self.out_toks,
-             self.acc_lp) = self._step_fn(
-                params, self.caches, self.cur_tok, self.out_toks,
-                self.acc_lp, self._put(self.block_tables),
-                self._put(self.true_lens), self._put(self.base_keys),
-                np.float32(temperature))
+            self.state = self._step_fn(params, self.state,
+                                       self._device_temp(temperature))
             finished = []
             active = 0
             for slot, meta in enumerate(self._slot_meta):
@@ -361,10 +397,11 @@ class PagedDecodeEngine:
             self.steps += 1
             self.slot_steps += active
             if finished:
-                out_host = np.asarray(self.out_toks)
-                lp_host = np.asarray(self.acc_lp)
+                out_host = np.asarray(self.state["out_toks"])
+                lp_host = np.asarray(self.state["acc_lp"])
                 for slot in finished:
                     self._retire(slot, out_host, lp_host)
+        self.step_host_s += time.perf_counter() - t0
 
     def run(self, params, temperature, specs=(), poll=None):
         """Decode ``specs`` (plus anything ``poll`` injects) to completion.

@@ -221,10 +221,65 @@ def test_engine_counts_steps_and_slot_steps():
     assert (eng.steps, eng.slot_steps) == (8 + 2, 13 + 2)
 
 
+def test_engine_device_slot_state_matches_host_mirrors():
+    """The device advances and retires slots by the host's own rule: after
+    every step of a run (rows of 3, 6 and 1 tokens, and one of 7 injected
+    by poll), the device's true lengths, block tables and base keys equal
+    the host's mirrors, which the engine never reads back."""
+    eng = prot.PagedDecodeEngine(CFG, slots=3, max_new=7, interpret=True)
+    specs = [dict(s, length=n) for s, n in zip(_specs(4, 7), (3, 6, 1, 7))]
+    orig, checked, calls = eng.step, [], []
+
+    def step(params, temperature):
+        orig(params, temperature)
+        for name in ("true_lens", "block_tables", "base_keys"):
+            np.testing.assert_array_equal(np.asarray(eng.state[name]),
+                                          getattr(eng, name), err_msg=name)
+        checked.append(eng.true_lens.copy())
+
+    def poll(free):
+        calls.append(free)
+        return [specs[3]] if len(calls) == 3 else []
+
+    eng.step = step
+    res = eng.run(PARAMS, 1.0, specs[:3], poll=poll)
+    assert len(checked) == 2 + (7 - 1) and set(res) == {0, 1, 2, 3}
+    assert (checked[1] == 0).sum() == 2       # rows of 3 and 1 retired
+    assert not checked[-1].any()
+    assert (eng.block_tables == eng.trash_page).all()
+
+
+def test_engine_step_makes_no_host_to_device_transfer():
+    """A warm decode step takes everything from the device: the per-slot
+    state is donated device state and the temperature is a device scalar,
+    so a step (retirement included) runs under a guard that refuses every
+    host-to-device transfer, explicit or implicit."""
+    eng = prot.PagedDecodeEngine(CFG, slots=2, max_new=6, interpret=True)
+    specs = _specs(2, length=6)
+    for s in specs:
+        eng.submit(**s)
+    eng._pump(PARAMS, 1.0)
+    eng.step(PARAMS, 1.0)                     # compiles the step
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        for _ in range(6 - 2):
+            eng.step(PARAMS, 1.0)
+    assert not eng.active_slots()             # the last step retired both
+    for s in specs:
+        toks, _ = _dense_rowsample(s["backbone"], s["key"], s["length"])
+        np.testing.assert_array_equal(eng._results[s["tag"]][0], toks)
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        with pytest.raises(Exception, match="host-to-device"):
+            jax.device_put(np.zeros(2, np.int32))
+        with pytest.raises(Exception, match="host-to-device"):
+            jax.jit(lambda x: x + 1)(np.float32(1.0))
+    assert eng.trace_counts == {"admit": 1, "step": 1}
+
+
 def test_gen_batch_log_carries_step_deltas():
     """Each paged dispatch's log record holds its own steps and active
     slot-steps (deltas of the engine's running counts), beside its slot
-    count: 1 row x 2 candidates of 6 tokens take 5 steps in 4 slots."""
+    count, and the host time of those steps: 1 row x 2 candidates of 6
+    tokens take 5 steps in 4 slots."""
     pp = ProteinPayload(jax.random.PRNGKey(0), reduced=True, length=6)
     mesh = _Mesh()
     at = len(gen_batch_log)
@@ -233,6 +288,7 @@ def test_gen_batch_log_carries_step_deltas():
     for rec in gen_batch_log[at:]:
         assert rec["bucket"] == 4
         assert (rec["steps"], rec["slot_steps"]) == (5, 2 * 5)
+        assert rec["step_host_s"] > 0
 
 
 # ---------------------------------------------------------------------------

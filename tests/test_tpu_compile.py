@@ -62,20 +62,21 @@ def _shapes(tree, sharding):
 @pytest.mark.parametrize("page_size", [8, 16])
 def test_paged_decode_step_compiles(one_chip, page_size):
     """The engine's own step program (paged decode attention through the
-    Pallas kernel, sampling, donated state) for 16 slots of 144 tokens."""
+    Pallas kernel, sampling, the slots' advance and retirement) for 16
+    slots of 144 tokens, with every per-slot array as donated state."""
     cfg = get_config("progen-s")
     eng = prot.PagedDecodeEngine(cfg, slots=SLOTS, max_new=MAX_NEW,
                                  page_size=page_size, interpret=False)
     params = _shapes(jax.eval_shape(
         lambda: prot.init_progen(jax.random.PRNGKey(0), cfg)), one_chip)
-    state = _shapes((eng.caches, eng.cur_tok, eng.out_toks, eng.acc_lp),
-                    one_chip)
-    host = _shapes((jnp.asarray(eng.block_tables), jnp.asarray(eng.true_lens),
-                    jnp.asarray(eng.base_keys)), one_chip)
+    state = _shapes(eng.state, one_chip)
     temp = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-    step = jax.jit(eng._build_step(), donate_argnums=(1, 2, 3, 4))
-    compiled = step.lower(params, *state, *host, temp).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    compiled = eng._step_fn.lower(params, state, temp).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    aliases = next(line for line in text.splitlines()
+                   if "input_output_alias" in line)
+    assert aliases.count("may-alias") == len(jax.tree.leaves(eng.state))
     assert eng.trace_counts["step"] == 1
 
 
