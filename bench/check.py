@@ -1,8 +1,8 @@
 """The comparison that decides ``correct``.
 
 A sample of what the timed path produced, drawn from the seed after the
-window has closed, is run through the plain float32 reference
-(``reference.py``) on the pipeline's own inputs:
+window has closed, is run through the plain float32 reference of each
+model's architecture (``archs/<arch>.py``) on the pipeline's own inputs:
 
 ``gen_ll_gap``   widest gap, in nats, between a sampled candidate's
                  log-likelihood as the program returned it and the
@@ -22,8 +22,6 @@ cache.
 from __future__ import annotations
 
 import numpy as np
-
-from bench import reference as ref
 
 BLOCK = 8
 RANGES = np.asarray([100.0, 1.0, 30.0])
@@ -48,9 +46,10 @@ def _blocks(items):
         yield chunk + [chunk[-1]] * (BLOCK - len(chunk)), len(chunk)
 
 
-def ref_lls(cands, params, m: dict, quant=None) -> np.ndarray:
+def ref_lls(cands, params, m: dict, arch, quant=None) -> np.ndarray:
     """The reference's log-likelihood of each candidate's tokens on its
-    structure prefix. ``cands``: (backbone prefix (P, 16), tokens (T,))."""
+    structure prefix. ``cands``: (backbone prefix (P, 16), tokens (T,));
+    ``arch``: the generator's architecture module."""
     mt = tuple(sorted(m.items()))
     T = _pad(max(len(c[1]) for c in cands))
     out = []
@@ -58,16 +57,17 @@ def ref_lls(cands, params, m: dict, quant=None) -> np.ndarray:
         toks = np.zeros((BLOCK, T), np.int32)
         for r, c in enumerate(chunk):
             toks[r, :len(c[1])] = c[1]
-        lp = np.asarray(ref.token_logprobs(
+        lp = np.asarray(arch.token_logprobs(
             params, np.stack([c[0] for c in chunk]), toks, m=mt,
             quant=quant), np.float64)
         out += [lp[r, :len(c[1])].sum() for r, c in enumerate(chunk[:real])]
     return np.asarray(out)
 
 
-def ref_scores(rows, params, m: dict, quant=None) -> np.ndarray:
+def ref_scores(rows, params, m: dict, arch, quant=None) -> np.ndarray:
     """The reference's (pLDDT, pTM, pAE) of each complex at its true
-    length. ``rows``: (complex (L,), target (16,), chain split)."""
+    length. ``rows``: (complex (L,), target (16,), chain split);
+    ``arch``: the scorer's architecture module."""
     mt = tuple(sorted(m.items()))
     L = _pad(max(len(r[0]) for r in rows))
     out = []
@@ -75,7 +75,7 @@ def ref_scores(rows, params, m: dict, quant=None) -> np.ndarray:
         seqs = np.zeros((BLOCK, L), np.int32)
         for i, r in enumerate(chunk):
             seqs[i, :len(r[0])] = r[0]
-        got = ref.fold_metrics(
+        got = arch.fold_metrics(
             params, seqs, np.stack([r[1] for r in chunk]),
             np.asarray([len(r[0]) for r in chunk], np.int32),
             np.asarray([r[2] for r in chunk], np.int32), m=mt, quant=quant)
@@ -98,27 +98,28 @@ def compare(recorder, weights, roles: dict, plan: dict, seed_words,
             control: bool = False) -> dict:
     """The numbers compared, each the widest gap over the sample against
     the float32 reference. ``roles`` maps (kind, param-set namespace) to
-    (role, sizes). With ``control`` the program's answers are replaced by
-    the control's: the reference computed at float8 (``quant="fp8"``) on
-    the same inputs, one precision step below the configuration's
-    bfloat16 -- the reading that sets a limit's upper end."""
+    (role, sizes, architecture module). With ``control`` the program's
+    answers are replaced by the control's: the reference computed at
+    float8 (``quant="fp8"``) on the same inputs, one precision step below
+    the configuration's bfloat16 -- the reading that sets a limit's upper
+    end."""
     rng = np.random.default_rng(np.asarray(seed_words, np.uint32))
     cands, rows = samples(recorder, plan, rng)
     gen, score = [], []
     for ns in sorted({g["ns"] for g, _ in cands}):
-        role, m = roles["generator", ns]
+        role, m, arch = roles["generator", ns]
         sel = [(g["backbone"], g["tokens"][k], g["ll"][k])
                for g, k in cands if g["ns"] == ns]
-        want = ref_lls(sel, weights[role], m)
-        got = (ref_lls(sel, weights[role], m, "fp8") if control
+        want = ref_lls(sel, weights[role], m, arch)
+        got = (ref_lls(sel, weights[role], m, arch, "fp8") if control
                else np.asarray([c[2] for c in sel]))
         gen.append(np.abs(got - want).max())
     for ns in sorted({r["ns"] for r in rows}):
-        role, m = roles["scorer", ns]
+        role, m, arch = roles["scorer", ns]
         sel = [(r["seq"], r["target"], r["split"]) for r in rows
                if r["ns"] == ns]
-        want = ref_scores(sel, weights[role], m)
-        got = (ref_scores(sel, weights[role], m, "fp8") if control
+        want = ref_scores(sel, weights[role], m, arch)
+        got = (ref_scores(sel, weights[role], m, arch, "fp8") if control
                else np.asarray([r["metrics"] for r in rows
                                 if r["ns"] == ns], np.float64))
         score.append((np.abs(got - want) / RANGES).max())

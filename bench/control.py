@@ -6,10 +6,11 @@ benchmark's runs.
 
     python3 bench/control.py --workload NAME --seconds S --seeds 1 2 3 ...
 
-The control is the float32 reference put in the program's place and
-computed at float8 (``reference.py``, ``quant="fp8"``), one precision step
-below the bfloat16 the configuration states. Prints one JSON line per
-seed: ``{"seed", "program": {gap: value}, "control": {gap: value}}``.
+The control is each model's float32 reference (its architecture's
+module, ``archs/<arch>.py``) put in the program's place and computed at
+float8 (``quant="fp8"``), one precision step below the bfloat16 the
+configuration states. Prints one JSON line per seed: ``{"seed",
+"program": {gap: value}, "control": {gap: value}}``.
 The largest program reading over a dozen seeds or more is a limit's
 lower reading; the smallest control reading its upper one.
 """

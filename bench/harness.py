@@ -8,6 +8,8 @@ kind or one per-layer metric lives in a file of its own, found by name:
 
 - ``BENCHMARK.json`` (at the checkout root): cells and metrics;
 - ``bench/configs/<config>.json``: model sizes, param sets, limits;
+- ``bench/archs/<arch>.py``: the weights, plain reference, control and
+  work counts of one model architecture, named by a model's ``"arch"``;
 - ``bench/traffic/<traffic>.json``: the entry kind and its load;
 - ``bench/entries/<entry>.py``: ``setup(run)``, ``window(run)``,
   ``results(run)`` of one way of driving the system;
@@ -16,6 +18,7 @@ kind or one per-layer metric lives in a file of its own, found by name:
 
 from __future__ import annotations
 
+import contextlib
 import importlib.util
 import json
 import os
@@ -27,7 +30,7 @@ from typing import Dict
 
 import numpy as np
 
-from bench import check, flops, mix, reference, trace as tracemod
+from bench import archs, check, flops, mix, trace as tracemod
 from bench.record import Recorder
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -123,29 +126,50 @@ def check_layout(config: dict, cfgs: dict, weights: dict):
                              f"program's")
 
 
+@contextlib.contextmanager
+def _init_returns(prot, generator=None, scorer=None):
+    """While it is open, the program's init of a generator or a scorer
+    returns the given weights. ``ProteinPayload``'s constructor takes no
+    weights and initialises its default models itself; an init whose
+    result is replaced at once costs set-up time and, at a large size, a
+    second copy of the model on the chip."""
+    saved = prot.init_progen, prot.init_foldscore
+    if generator is not None:
+        prot.init_progen = lambda key, cfg: generator
+    if scorer is not None:
+        prot.init_foldscore = lambda key, cfg: scorer
+    try:
+        yield
+    finally:
+        prot.init_progen, prot.init_foldscore = saved
+
+
 def build_payload(config: dict, cfgs: dict, weights: dict, length: int):
     """A ``ProteinPayload`` serving the benchmark's weights under the
-    configuration's param-set namespaces."""
+    configuration's param-set namespaces: its default generator and
+    scorer are the configuration's ``default`` models, where it has
+    them, built on the benchmark's weights and never initialised."""
     import jax
     from repro.core import ProteinPayload
     from repro.learn.param_store import ParamStore
-    payload = ProteinPayload(jax.random.PRNGKey(0), length=length,
-                             reduced=config.get("program_preset")
-                             == "reduced")
+    from repro.models import protein as prot
+    default = {m["kind"]: role for role, m in config["models"].items()
+               if m["param_set"] == "default"}
+    gen, fold = default.get("generator"), default.get("scorer")
+    with _init_returns(prot, weights.get(gen), weights.get(fold)):
+        payload = ProteinPayload(
+            jax.random.PRNGKey(0), gen_cfg=cfgs.get(gen),
+            fold_cfg=cfgs.get(fold), length=length,
+            reduced=config.get("program_preset") == "reduced")
     for role, mdl in config["models"].items():
         ns, cfg, p = mdl["param_set"], cfgs[role], weights[role]
+        if ns == "default":
+            continue
         if mdl["kind"] == "generator":
-            if ns == "default":
-                if cfg != payload.gen_cfg:
-                    raise ValueError("default generator config differs")
-                payload.param_store.publish(p)
-            else:
-                payload.gen_stores[ns] = ParamStore(p)
-                payload.gen_cfgs[ns] = cfg
+            payload.gen_stores[ns] = ParamStore(p)
+            payload.gen_cfgs[ns] = cfg
         else:
             payload.fold_sets[ns] = (cfg, p)
-            if ns == "default":
-                payload.fold_params = p
     return payload
 
 
@@ -181,17 +205,18 @@ class Run:
         from repro.session import enable_compilation_cache
         enable_compilation_cache()
         self.cfgs = program_configs(self.config)
-        self.weights = reference.make_weights(
+        models = self.config["models"]
+        self.sizes = {r: dict(m["sizes"], **self.config["architecture"])
+                      for r, m in models.items()}
+        self.archs = {r: archs.load(archs.name_of(m), self.bench_dir)
+                      for r, m in models.items()}
+        self.weights = archs.make_weights(
             np.random.SeedSequence([self.seed, 0]).generate_state(
                 2, dtype=np.uint32),
-            {r: {"kind": m["kind"], "sizes": m["sizes"]}
-             for r, m in self.config["models"].items()},
+            {r: (self.archs[r], m["kind"], self.sizes[r])
+             for r, m in models.items()},
             device=self.devices[0])
         check_layout(self.config, self.cfgs, self.weights)
-        arch = self.config["architecture"]
-        self.sizes = {r: dict(m["sizes"], norm_eps=arch["norm_eps"],
-                              rope_theta=arch["rope_theta"])
-                      for r, m in self.config["models"].items()}
         self.payload = build_payload(self.config, self.cfgs, self.weights,
                                      max(mix.receptor_lens(self.traffic)))
         prefix = max(int(m["sizes"].get("frontend_seq", 0))
@@ -199,16 +224,18 @@ class Run:
         self.recorder = Recorder(prefix)
 
     def roles(self):
-        """(kind, namespace) -> (role, sizes), for the comparison."""
-        return {(m["kind"], m["param_set"]): (r, self.sizes[r])
+        """(kind, namespace) -> (role, sizes, architecture module)."""
+        return {(m["kind"], m["param_set"]): (r, self.sizes[r],
+                                              self.archs[r])
                 for r, m in self.config["models"].items()}
 
     # -- instruments for the traced run ----------------------------------------
 
     def instrument(self):
-        """Count the work of the paged decode kernel and of the scorer
-        executables while the profiler runs: wraps the warm engines'
-        ``step`` and the warm scorer executables of the payload's cache."""
+        """Count the work of the paged decode step's kernels and of the
+        scorer executables, as each model's architecture counts it, while
+        the profiler runs: wraps the warm engines' ``step`` and the warm
+        scorer executables of the payload's cache."""
         from repro.models.protein import PagedDecodeEngine
         try:
             self.peak = tracemod.peak_for(self.devices[0].device_kind,
@@ -217,25 +244,19 @@ class Run:
             if self.devices[0].platform == "tpu":
                 raise
             self.peak = None        # no device numbers off the chip
-        p = self.payload
-        gen_m = {ns: self._sizes_of("generator", ns) for ns in p.gen_cfgs}
-        fold_m = {ns: self._sizes_of("scorer", ns) for ns in p.fold_sets}
+        p, roles = self.payload, self.roles()
         for key, val in list(p._cache.items()):
             kind = key[0]
             if isinstance(val, PagedDecodeEngine):
                 ns = kind.split("@", 1)[1] if "@" in kind else "default"
-                val.step = self._count_step(val, gen_m[ns])
+                val.step = self._count_step(val, roles.get(("generator",
+                                                            ns)))
             elif isinstance(kind, str) and kind.startswith("predict_mb"):
                 shape, _, ns = kind.partition("@")
                 per, L = shape[len("predict_mb"):].split("_L")
                 p._cache[key] = self._count_call(
-                    val, fold_m[ns or "default"], int(per), int(L))
-
-    def _sizes_of(self, kind, ns):
-        for r, m in self.config["models"].items():
-            if m["kind"] == kind and m["param_set"] == ns:
-                return self.sizes[r]
-        return None
+                    val, roles.get(("scorer", ns or "default")), int(per),
+                    int(L))
 
     def _add(self, name, f, b):
         """One call's operations, bytes and least time on the chip."""
@@ -245,20 +266,24 @@ class Run:
         t[2] += b
         t[3] += flops.roofline_s(f, b, self.peak) if self.peak else 0.0
 
-    def _count_step(self, eng, m):
+    def _count_step(self, eng, role):
+        """``role``: the generator's (role, sizes, architecture), or None
+        for a model the configuration does not state."""
         orig = eng.step
 
         def step(params, temperature):
-            if self.tracing and m is not None:
+            if self.tracing and role is not None:
+                _, m, arch = role
                 lens = eng.true_lens
-                f, b = flops.paged_decode_step(m, np.where(lens > 0,
-                                                           lens + 1, 0))
-                self._add("paged_decode", f, b)
+                counts = arch.step_counts(m, np.where(lens > 0, lens + 1, 0))
+                for name, (f, b) in counts.items():
+                    self._add(name, f, b)
             return orig(params, temperature)
         return step
 
-    def _count_call(self, fn, m, per, L):
-        f, b = flops.scorer_call(m, per, L) if m is not None else (0, 0)
+    def _count_call(self, fn, role, per, L):
+        f, b = (role[2].scorer_call(role[1], per, L) if role is not None
+                else (0, 0))
 
         def call(*a, **kw):
             if self.tracing:
@@ -295,11 +320,19 @@ class Run:
 
     def execute(self) -> dict:
         from repro.obs import CompileWatcher, MetricsRegistry
-        self.build()
-        built = time.monotonic()
-        self.entry.setup(self)
+        setup_reg = MetricsRegistry()
+        with CompileWatcher(setup_reg):
+            self.build()
+            built = time.monotonic()
+            self.entry.setup(self)
         self.setup_phases = {"build_s": built - self.t_start,
                              "entry_setup_s": time.monotonic() - built}
+        # JAX's compile events of the set-up, [count, seconds] by event:
+        # a set-up that finds its programs in the cache retrieves them
+        # (``cache_retrieval_time_sec``) and compiles none
+        # (``backend_compile_duration``).
+        for key, h in setup_reg.series("jax.compile_s").items():
+            self.setup_phases[dict(key[1:])["event"]] = [h.count, h.sum]
         if self.trace:
             self.instrument()
         self.t0 = time.monotonic()

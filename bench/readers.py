@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import flops, trace as tracemod
+from bench import trace as tracemod
 
 # Names the reduction finds the kernels by. The Pallas paged decode
 # kernel's custom call is named after ``ops.paged_decode_attention``. The
@@ -51,7 +51,8 @@ def rows_per_dispatch(entries):
 
 def payload_mfu(ctx):
     """Useful model operations of the window (tokens sampled, residues
-    scored, padding excluded) over window x chips x bf16 peak, in %."""
+    scored, padding excluded), as each model's architecture counts them,
+    over window x chips x bf16 peak, in %."""
     run, peak = ctx["run"], ctx["peak"]
     if peak is None:
         return None
@@ -60,13 +61,13 @@ def payload_mfu(ctx):
     useful = 0
     for g in run.recorder.gen:
         if t0 <= g["t"] <= t1:
-            m = roles["generator", g["ns"]][1]
-            useful += sum(flops.generator_flops(m, len(t))
+            _, m, arch = roles["generator", g["ns"]]
+            useful += sum(arch.generator_flops(m, len(t))
                           for t in g["tokens"])
     for r in run.recorder.scores:
         if t0 <= r["t"] <= t1:
-            useful += flops.scorer_flops(roles["scorer", r["ns"]][1],
-                                         len(r["seq"]))
+            _, m, arch = roles["scorer", r["ns"]]
+            useful += arch.scorer_flops(m, len(r["seq"]))
     if not useful:
         return None
     return 100.0 * useful / ((t1 - t0) * run.chips

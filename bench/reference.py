@@ -1,10 +1,10 @@
 """The benchmark's own weights and plain float32 reference of the payload
 models. Imports nothing of the program.
 
-Weights: ``make_weights`` builds every model of a configuration from the
-seed in one jitted call, in float32 (the configuration's parameter type)
-and in the program's parameter layout, so the same arrays can be handed to
-the program and to the reference.
+Weights: ``generator_params`` and ``scorer_params`` build a model from a
+key in float32 (the configuration's parameter type) and in the program's
+parameter layout, so the same arrays can be handed to the program and to
+the reference. ``archs/dense.py`` binds them and the forward below.
 
 Reference: a straightforward ``jax.numpy`` forward in float32 at
 ``precision="highest"``, one layer after the other, following the model
@@ -92,25 +92,6 @@ def scorer_params(key, m: dict) -> dict:
                   "pae_r": _normal(k[3], (d, PAE_RANK), d),
                   "tgt": _normal(k[4], (FEAT, d), FEAT)}
     return p
-
-
-BUILDERS = {"generator": generator_params, "scorer": scorer_params}
-
-
-def make_weights(key_words, models: dict, device=None) -> dict:
-    """Every model of a configuration, ``{role: params}``, from a raw
-    (2,) uint32 key in one jitted call on ``device``."""
-    roles = sorted(models)
-
-    def build(key):
-        keys = jax.random.split(key, len(roles))
-        return {r: BUILDERS[models[r]["kind"]](k, models[r]["sizes"])
-                for r, k in zip(roles, keys)}
-
-    key = jnp.asarray(np.asarray(key_words, np.uint32))
-    if device is not None:
-        key = jax.device_put(key, device)
-    return jax.jit(build)(key)
 
 
 # -- forward -------------------------------------------------------------------
