@@ -41,6 +41,11 @@ class NoChip(Exception):
     """The machine does not hold what the cell needs."""
 
 
+class TraceError(RuntimeError):
+    """A traced run on the chip whose trace cannot give the slice's device
+    numbers: no slice span, or no operation of the cell's devices."""
+
+
 def load_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
@@ -294,7 +299,10 @@ class Run:
     # -- the traced slice ------------------------------------------------------
 
     def _profile(self, t0):
-        """Profile a steady slice of the window on a thread of its own."""
+        """Profile a steady slice of the window on a thread of its own.
+        The slice is the ``bench.slice`` span, on the profiler's clock,
+        around the time the instruments count: the reduction clips every
+        device number to it, so none covers the profiler's start or stop."""
         import jax
         tr = self.traffic["trace"]
         lead = min(float(tr["lead_s"]), self.seconds / 4)
@@ -305,9 +313,10 @@ class Run:
             time.sleep(max(0.0, t0 + lead - time.monotonic()))
             jax.profiler.start_trace(self.trace_dir)
             a = time.monotonic()
-            self.tracing = True
-            time.sleep(span)
-            self.tracing = False
+            with jax.profiler.TraceAnnotation(tracemod.SLICE):
+                self.tracing = True
+                time.sleep(span)
+                self.tracing = False
             b = time.monotonic()
             jax.profiler.stop_trace()
             self.slice_s = b - a
@@ -376,8 +385,24 @@ class Run:
         return out
 
     def reduce_trace(self):
+        """The slice's device numbers. On a TPU the trace must hold the
+        slice span and an operation on each of the cell's devices; off
+        the chip a trace without the span keeps the host's ``slice_s``."""
         red = tracemod.reduce(self.trace_dir, n_devices=self.chips)
         shutil.rmtree(self.trace_dir, ignore_errors=True)
+        if self.devices[0].platform == "tpu":
+            if red["window_s"] is None:
+                raise TraceError(f"the trace has no {tracemod.SLICE} span "
+                                 f"on its host plane")
+            if red["devices"] < self.chips:
+                raise TraceError(f"the trace has {red['devices']} "
+                                 f"{tracemod.DEVICE_PREFIX}<n> planes; the "
+                                 f"cell runs on {self.chips} chips")
+            if not red["busy_s"] > 0:
+                raise TraceError("no operation ran on the device inside "
+                                 "the slice")
+        elif red["window_s"] is None:
+            red["window_s"] = self.slice_s
         return red
 
 
@@ -404,7 +429,7 @@ def run(name, seed, seconds, trace, **kw) -> dict:
     if r.trace:
         red = r.reduce_trace()
         device["busy_s"] = red["busy_s"]
-        device["window_s"] = r.slice_s
+        device["window_s"] = red["window_s"]
         ctx = {"run": r, "trace": red, "peak": r.peak, "e2e": e2e}
         for mtr in r.metrics_for("per_layer"):
             mod = load_module(os.path.join(r.bench_dir, "metrics",

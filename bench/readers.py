@@ -76,7 +76,9 @@ def payload_mfu(ctx):
 
 def roofline(ctx, counted: str, times: str, patterns):
     """100 x the least time the counted calls could take over the time
-    their events took in the trace."""
+    their events took in the trace. Both cover the slice: the calls are
+    counted while ``run.tracing`` is on, inside the slice span, and the
+    events' times are clipped to that span."""
     run = ctx["run"]
     t = tracemod.time_matching(ctx["trace"][times], *patterns)
     work = run.traced.get(counted)
@@ -86,7 +88,8 @@ def roofline(ctx, counted: str, times: str, patterns):
 
 
 def idle_share(ctx):
-    run, red = ctx["run"], ctx["trace"]
-    if not getattr(run, "slice_s", 0) or not red["devices"]:
+    """100 x (1 - busy / slice), both clipped to the slice span."""
+    red = ctx["trace"]
+    if not red["window_s"] or not red["devices"]:
         return None
-    return 100.0 * (1.0 - red["busy_s"] / run.slice_s)
+    return 100.0 * (1.0 - red["busy_s"] / red["window_s"])

@@ -12,7 +12,8 @@ The last line of standard output is the result object; the numbers
 compared with the reference are also the last lines of standard error.
 On a platform other than TPU, with fewer devices than the cell needs, or
 with Pallas kernels that would run interpreted, it exits non-zero and
-prints no result.
+prints no result; so does a traced run whose trace has no ``bench.slice``
+span or no operation of the cell's devices inside it.
 """
 
 import time

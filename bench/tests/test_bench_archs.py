@@ -268,6 +268,8 @@ def test_new_architecture_is_new_files(tree, no_compile_cache, monkeypatch):
     res = harness.run("imrp-probe", 2 ** 33 + 17, 3.0, 1, root=tree,
                       bench_dir=bench, require_tpu=False)
     assert res["correct"] is True, res["checks"]
+    dev = res["device"]
+    assert 0 < dev["window_s"] and 0 <= dev["busy_s"] <= dev["window_s"]
     assert res["metrics"]["probe_steps"]["value"] > 0
     assert res["metrics"]["payload_mfu"]["value"] > 0
     from bench import archs

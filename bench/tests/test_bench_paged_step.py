@@ -54,5 +54,7 @@ def test_traced_run_reports_step_time(tree, no_compile_cache):
     res = harness.run("imrp-tiny", 4294967311, 3.0, 1, root=tree,
                       bench_dir=tree + "/bench", require_tpu=False)
     assert res["correct"] is True, res["checks"]
+    dev = res["device"]
+    assert 0 < dev["window_s"] and 0 <= dev["busy_s"] <= dev["window_s"]
     m = res["metrics"][NAME]
     assert m["unit"] == "ms" and m["value"] > 0

@@ -72,6 +72,8 @@ def test_traced_run_reports_the_new_metrics(tree, no_compile_cache):
                       bench_dir=tree + "/bench", require_tpu=False)
     m = res["metrics"]
     assert res["correct"] is True, res["checks"]
+    dev = res["device"]
+    assert 0 < dev["window_s"] and 0 <= dev["busy_s"] <= dev["window_s"]
     assert 0 < m["decode_slot_fill"]["value"] <= 100
     assert 0 < m["coordinator_busy_share"]["value"] < 100
     assert 0 < m["gc_pause_share"]["value"] < 100

@@ -7,10 +7,14 @@ Device planes are those named ``/device:TPU:<n>``; on each, the ``XLA
 Ops`` line holds one event per operation run, named by its HLO text
 (fusions, ``while`` loops with their body's ops nested inside, custom
 calls such as the Pallas kernels), and the ``XLA Modules`` line one event
-per executable run, named ``jit_<function>(<id>)``. Busy time is the union
-of a device's operation intervals; idle gaps are the holes between them,
-each attributed to the innermost host event (any thread of the host
-plane, Python frames included) that covers the middle of the gap. The
+per executable run, named ``jit_<function>(<id>)``. The harness marks the
+profiled slice with a host span named ``bench.slice`` (``SLICE``), on the
+profiler's clock like the device's events; every device number is
+clipped to it, each interval counting only its part in ``[start, end)``.
+Busy time is the union of a device's clipped operation intervals, so it
+never exceeds the slice; idle gaps are the rest of the slice, each
+attributed to the innermost host event (any thread of the host plane,
+Python frames included) that covers the middle of the gap. The
 breakdown's device operations are ranked by self time, nested ops taken
 out of the ops that hold them.
 """
@@ -28,6 +32,7 @@ import numpy as np
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+SLICE = "bench.slice"
 TOP = 10
 
 
@@ -61,6 +66,13 @@ def union(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
     return [(s, e) for s, e in out]
 
 
+def clip(s: int, e: int, window) -> int:
+    """Nanoseconds of [s, e) inside ``window`` (all of them if None)."""
+    if window is None:
+        return e - s
+    return max(0, min(e, window[1]) - max(s, window[0]))
+
+
 def _events(line):
     return [(ev.name, int(ev.start_ns), int(ev.duration_ns))
             for ev in line.events]
@@ -80,18 +92,22 @@ def op_name(text: str) -> str:
     return f"{name} {kind[:48]}".strip()
 
 
-def self_times(events) -> Dict[str, float]:
-    """Seconds by short op name, each event counted without the time of
-    the events nested inside it (an XLA ``while`` holds its body's ops)."""
+def self_times(events, window=None) -> Dict[str, float]:
+    """Seconds by short op name inside ``window``, each event counted
+    without the time of the events nested inside it (an XLA ``while``
+    holds its body's ops). Nesting is read from the whole events, so an
+    op that straddles an edge keeps its children."""
     out: Dict[str, float] = defaultdict(float)
     stack: List[list] = []          # [end, name, self_ns]
     for name, s, d in sorted(events, key=lambda e: (e[1], -e[2])):
+        if window is not None and (s + d <= window[0] or s >= window[1]):
+            continue    # outside the window, as is every op nested in it
         while stack and stack[-1][0] <= s:
             end, n, own = stack.pop()
             out[n] += own * 1e-9
         if stack:
-            stack[-1][2] -= min(d, stack[-1][0] - s)
-        stack.append([s + d, op_name(name), d])
+            stack[-1][2] -= clip(s, min(s + d, stack[-1][0]), window)
+        stack.append([s + d, op_name(name), clip(s, s + d, window)])
     for end, n, own in stack:
         out[n] += own * 1e-9
     return out
@@ -99,7 +115,8 @@ def self_times(events) -> Dict[str, float]:
 
 def read_planes(path: str):
     """({device plane name: {line name: [(name, start_ns, dur_ns)]}},
-    [host events])."""
+    [host events], (start_ns, end_ns) of the ``SLICE`` span or None).
+    The span is taken out of the host events."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     devices, host = {}, []
@@ -110,13 +127,19 @@ def read_planes(path: str):
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 host += _events(line)
-    return devices, host
+    spans = [(s, s + d) for name, s, d in host if name == SLICE]
+    if len(spans) > 1:
+        raise ValueError(f"{len(spans)} {SLICE} spans in {path}")
+    host = [h for h in host if h[0] != SLICE]
+    return devices, host, (spans[0] if spans else None)
 
 
 def reduce_planes(devices: Dict[str, Dict[str, list]], host: list,
-                  n_devices: int) -> dict:
-    """The trace's numbers from already-read planes (see ``read_planes``).
-    ``busy_s`` is averaged over the first ``n_devices`` device planes."""
+                  n_devices: int, window=None) -> dict:
+    """The trace's numbers from already-read planes (see ``read_planes``),
+    every interval clipped to ``window`` = (start_ns, end_ns) where it is
+    given. ``busy_s`` is averaged over the first ``n_devices`` device
+    planes; ``window_s`` is the window's length, None without one."""
     names = sorted(devices, key=lambda n: int(n[len(DEVICE_PREFIX):]
                                               .split()[0])
                    if n[len(DEVICE_PREFIX):].split()[0].isdigit() else 1e9)
@@ -124,19 +147,28 @@ def reduce_planes(devices: Dict[str, Dict[str, list]], host: list,
     op_time: Dict[str, float] = defaultdict(float)
     own_time: Dict[str, float] = defaultdict(float)
     module_time: Dict[str, float] = defaultdict(float)
-    busy, gaps = [], []
+    busy_ns, gaps = 0, []
     for n in names:
         lines = devices[n]
         ops = lines.get(OPS_LINE, [])
-        for name, _, d in ops:
-            op_time[name.partition(" = ")[0].lstrip("%")] += d * 1e-9
-        for name, v in self_times(ops).items():
+        for name, s, d in ops:
+            op_time[name.partition(" = ")[0].lstrip("%")] += \
+                clip(s, s + d, window) * 1e-9
+        for name, v in self_times(ops, window).items():
             own_time[name] += v
-        for name, _, d in lines.get(MODULES_LINE, []):
-            module_time[name] += d * 1e-9
-        u = union([(s, s + d) for _, s, d in ops])
-        busy.append(sum(e - s for s, e in u) * 1e-9)
-        gaps += [(u[i][1], u[i + 1][0]) for i in range(len(u) - 1)]
+        for name, s, d in lines.get(MODULES_LINE, []):
+            module_time[name] += clip(s, s + d, window) * 1e-9
+        spans = [(s, s + d) for _, s, d in ops]
+        if window is not None:
+            lo, hi = window
+            spans = [(max(s, lo), min(e, hi)) for s, e in spans
+                     if min(e, hi) > max(s, lo)]
+        u = union(spans)
+        busy_ns += sum(e - s for s, e in u)
+        # inside a window, its edges bound the first and the last gap
+        edges = u if window is None else [(lo, lo)] + u + [(hi, hi)]
+        gaps += [(a[1], b[0]) for a, b in zip(edges, edges[1:])
+                 if b[0] > a[1]]
     idle_by: Dict[str, float] = defaultdict(float)
     h_start = np.asarray([h[1] for h in host], np.int64)
     h_end = h_start + np.asarray([h[2] for h in host], np.int64)
@@ -148,8 +180,11 @@ def reduce_planes(devices: Dict[str, Dict[str, list]], host: list,
         idle_by[what] += (e - s) * 1e-9
     top = lambda d: [[k, v] for k, v in sorted(d.items(),
                                                key=lambda kv: -kv[1])[:TOP]]
+    window_s = None if window is None else (window[1] - window[0]) * 1e-9
     return {
-        "busy_s": sum(busy) / max(len(busy), 1),
+        # the mean in whole nanoseconds first: never above the window
+        "busy_s": busy_ns / max(len(names), 1) * 1e-9,
+        "window_s": window_s,
         "devices": len(names),
         "op_time": dict(op_time),
         "module_time": dict(module_time),
@@ -159,8 +194,10 @@ def reduce_planes(devices: Dict[str, Dict[str, list]], host: list,
 
 
 def reduce(trace_dir: str, n_devices: int) -> dict:
-    devices, host = read_planes(find_xplane(trace_dir))
-    return reduce_planes(devices, host, n_devices)
+    """The numbers of the trace under ``trace_dir``, clipped to its
+    ``SLICE`` span where it has one."""
+    devices, host, window = read_planes(find_xplane(trace_dir))
+    return reduce_planes(devices, host, n_devices, window)
 
 
 def time_matching(times: Dict[str, float], *patterns: str) -> float:
