@@ -71,8 +71,9 @@ compile_log: Dict[str, list] = {"generate": [], "predict": []}
 batch_log: List[dict] = []
 
 # Same, for generate_batch dispatches; a paged dispatch adds the decode
-# steps it ran, the sum over them of active slots and the host time they
-# took (``steps``, ``slot_steps``, ``step_host_s``), beside its slot count
+# steps it ran, the sums over them of active slots and of the live pages
+# the attention kernel fetched, and the host time they took (``steps``,
+# ``slot_steps``, ``page_steps``, ``step_host_s``), beside its slot count
 # (``bucket``).
 gen_batch_log: List[dict] = []
 
@@ -700,6 +701,7 @@ class ProteinPayload:
 
         with eng.lock:
             steps0, slot_steps0 = eng.steps, eng.slot_steps
+            page_steps0 = eng.page_steps
             step_s0 = eng.step_host_s
             try:
                 res = eng.run(gp, temp,
@@ -725,6 +727,7 @@ class ProteinPayload:
                  "decode": "paged", "admitted": len(admitted),
                  "steps": eng.steps - steps0,
                  "slot_steps": eng.slot_steps - slot_steps0,
+                 "page_steps": eng.page_steps - page_steps0,
                  "step_host_s": eng.step_host_s - step_s0}
         gen_batch_log.append(batch)
         return {"rows": rows, "batch": dict(batch), "gen_version": ver}

@@ -161,6 +161,8 @@ class PagedDecodeEngine:
     admitting/retiring rows must keep it constant (the zero-recompile
     probe the tests assert). ``steps`` and ``slot_steps`` count decode
     steps and, summed over them, the slots active in each;
+    ``page_steps`` sums the live pages the attention kernel fetches,
+    ``ceil(length / page_size)`` per active slot and step;
     ``step_host_s`` sums the host wall time spent in ``step``. ``step``
     and ``_admit`` run inside the ``impress.paged.step`` /
     ``impress.paged.admit`` program spans.
@@ -206,6 +208,7 @@ class PagedDecodeEngine:
         self.trace_counts = {"admit": 0, "step": 0}
         self.steps = 0                                  # decode steps run
         self.slot_steps = 0                             # sum of active slots
+        self.page_steps = 0                             # sum of live pages
         self.step_host_s = 0.0                          # host time in step()
         self._temp = (None, None)                       # (value, on device)
         # device state, donated to every admit/step: the update is
@@ -390,12 +393,15 @@ class PagedDecodeEngine:
                 if meta is None:
                     continue
                 active += 1
-                self.true_lens[slot] += 1
+                self.true_lens[slot] += 1       # the kernel's length
                 meta["done"] += 1
                 if meta["done"] >= meta["length"]:
                     finished.append(slot)
             self.steps += 1
             self.slot_steps += active
+            # inactive slots hold length 0, so they add no page
+            pages = -(-self.true_lens // self.page_size)
+            self.page_steps += int(pages.sum())
             if finished:
                 out_host = np.asarray(self.state["out_toks"])
                 lp_host = np.asarray(self.state["acc_lp"])

@@ -59,12 +59,16 @@ def _dense_oracle(q, kp, vp, bt, lens, page):
 
 @pytest.mark.parametrize("B,KV,G,hd,page,maxp", [
     (4, 2, 2, 16, 4, 3), (3, 1, 4, 32, 8, 2), (6, 2, 1, 16, 8, 4),
+    (32, 4, 2, 32, 8, 26),                    # imrp-paged-mixlen's shapes
+    (6, 2, 2, 32, 16, 3),                     # page 16
 ])
 def test_paged_kernel_parity(B, KV, G, hd, page, maxp):
     rng = np.random.default_rng(3)
     q, kp, vp, bt = _rand_paged(rng, B, KV, G, hd, page, maxp, P=maxp * B)
     lens = rng.integers(0, maxp * page + 1, size=B).astype(np.int32)
-    lens[0] = 0                               # inactive slot
+    # an inactive slot, one entry, a length on a page boundary, full
+    edges = [0, 1, page, maxp * page][:B]
+    lens[:len(edges)] = edges
     args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
             jnp.asarray(bt), jnp.asarray(lens))
     kern = np.asarray(pa.paged_decode_bkgh(*args, page_size=page,
@@ -75,6 +79,81 @@ def test_paged_kernel_parity(B, KV, G, hd, page, maxp):
     assert_allclose(ref, oracle, atol=1e-5, rtol=1e-5)
     assert_allclose(kern, ref, atol=1e-5, rtol=1e-5)
     assert np.all(kern[lens == 0] == 0.0)     # inactive rows exactly zero
+
+
+@pytest.mark.parametrize("page,maxp", [(8, 26), (16, 3)])
+def test_paged_kernel_ignores_nan_dead_pages(page, maxp):
+    """Rows own distinct pages, as the engine's rows do; every page that
+    holds no live entry, the trash page among them, is NaN. The kernel
+    reads only live entries: it matches the oracle and the vectorized twin
+    (which multiplies dead V rows by 0, so it gets the pools with the NaN
+    pages zeroed), and inactive rows stay exactly zero."""
+    B, KV, G, hd = 8, 4, 2, 32
+    rng = np.random.default_rng(5)
+    P = B * maxp + 1
+    trash = P - 1
+    q, kp, vp, _ = _rand_paged(rng, B, KV, G, hd, page, maxp, P)
+    bt = rng.permutation(P - 1).astype(np.int32).reshape(B, maxp)
+    lens = np.asarray([0, 1, page, maxp * page, page + 3, 0, 2 * page - 1,
+                       maxp * page - 5], np.int32)
+    live = np.zeros(P, bool)
+    for b in range(B):
+        n = -(-int(lens[b]) // page)
+        live[bt[b, :n]] = True
+        bt[b, n:n + 1] = trash                # dead entries: trash or unused
+    kn, vn = kp.copy(), vp.copy()
+    kn[~live], vn[~live] = np.nan, np.nan
+    kern = np.asarray(pa.paged_decode_bkgh(
+        jnp.asarray(q), jnp.asarray(kn), jnp.asarray(vn), jnp.asarray(bt),
+        jnp.asarray(lens), page_size=page, interpret=True))
+    ref = np.asarray(pa.paged_decode_ref(
+        jnp.asarray(q), jnp.asarray(np.nan_to_num(kn, nan=0.0)),
+        jnp.asarray(np.nan_to_num(vn, nan=0.0)), jnp.asarray(bt),
+        jnp.asarray(lens), page_size=page))
+    assert np.isfinite(kern).all()
+    assert_allclose(kern, _dense_oracle(q, kn, vn, bt, lens, page),
+                    atol=1e-5, rtol=1e-5)
+    assert_allclose(kern, ref, atol=1e-5, rtol=1e-5)
+    assert np.all(kern[lens == 0] == 0.0)
+
+
+@pytest.mark.parametrize("cap,maxp", [(3, 7), (2, 6), (1, 3)])
+def test_paged_kernel_parity_across_chunks(monkeypatch, cap, maxp):
+    """With a VMEM budget of ``cap`` page positions a row's pages split
+    into chunks walked on the grid's second axis, the softmax carried
+    across them: the last chunk may run past ``maxp``. Dead pages are NaN;
+    the output still matches the oracle, inactive rows exactly zero."""
+    B, KV, G, hd, page = 6, 2, 2, 16, 4
+    block = KV * 8 * 128 * 4                  # an f32 (1, 2, 4, 16) block
+    monkeypatch.setattr(pa, "_PAGE_VMEM_BYTES", cap * 4 * block)
+    assert pa.pages_per_step(maxp, KV, page, hd, jnp.float32) <= cap
+    rng = np.random.default_rng(cap)
+    P = B * maxp + 1
+    q, kp, vp, _ = _rand_paged(rng, B, KV, G, hd, page, maxp, P)
+    bt = rng.permutation(P - 1).astype(np.int32).reshape(B, maxp)
+    lens = np.asarray([0, 1, page, maxp * page, cap * page + 1,
+                       maxp * page - 3], np.int32)
+    live = np.zeros(P, bool)
+    for b in range(B):
+        n = -(-int(lens[b]) // page)
+        live[bt[b, :n]] = True
+    kp[~live], vp[~live] = np.nan, np.nan
+    kern = np.asarray(pa.paged_decode_bkgh(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
+        jnp.asarray(lens), page_size=page, interpret=True))
+    assert_allclose(kern, _dense_oracle(q, kp, vp, bt, lens, page),
+                    atol=1e-5, rtol=1e-5)
+    assert np.all(kern[0] == 0.0)
+
+
+@pytest.mark.parametrize("maxp,page,want", [
+    (26, 8, 26),                              # imrp-paged-mixlen: one step
+    (128, 8, 128), (129, 8, 65), (520, 8, 104), (260, 16, 87),
+])
+def test_pages_per_step_fits_the_vmem_budget(maxp, page, want):
+    """bf16 blocks of (1, 4, page, 32) take 16 KiB tiled; K and V double
+    buffered fit 128 of them into 8 MiB, and longer rows split evenly."""
+    assert pa.pages_per_step(maxp, 4, page, 32, jnp.bfloat16) == want
 
 
 # ---------------------------------------------------------------------------
@@ -221,6 +300,38 @@ def test_engine_counts_steps_and_slot_steps():
     assert (eng.steps, eng.slot_steps) == (8 + 2, 13 + 2)
 
 
+def test_engine_page_steps_counts_live_pages():
+    """``page_steps`` grows in every step by the live pages the kernel
+    fetches, ``ceil(length / page_size)`` summed over the active slots,
+    where a slot's length in the step is its true length before it plus
+    the token the step writes; recomputed here from the host's mirrors
+    (rows of 3, 6 and 1 tokens, and one of 7 injected by poll)."""
+    eng = prot.PagedDecodeEngine(CFG, slots=3, max_new=7, page_size=4,
+                                 interpret=True)
+    specs = [dict(s, length=n) for s, n in zip(_specs(4, 7), (3, 6, 1, 7))]
+    orig, deltas, calls = eng.step, [], []
+
+    def step(params, temperature):
+        want = sum(-(-(int(eng.true_lens[slot]) + 1) // eng.page_size)
+                   for slot, meta in enumerate(eng._slot_meta)
+                   if meta is not None)
+        before = eng.page_steps
+        orig(params, temperature)
+        deltas.append((eng.page_steps - before, want))
+
+    def poll(free):
+        calls.append(free)
+        return [specs[3]] if len(calls) == 3 else []
+
+    eng.step = step
+    eng.run(PARAMS, 1.0, specs[:3], poll=poll)
+    assert len(deltas) == 2 + (7 - 1)
+    assert all(got == want > 0 for got, want in deltas), deltas
+    assert eng.page_steps == sum(w for _, w in deltas)
+    # one slot of S0 + 1 .. S0 + 6 entries over 4-entry pages
+    assert deltas[-1][1] == -(-(S0 + 6) // 4)
+
+
 def test_engine_device_slot_state_matches_host_mirrors():
     """The device advances and retires slots by the host's own rule: after
     every step of a run (rows of 3, 6 and 1 tokens, and one of 7 injected
@@ -276,10 +387,10 @@ def test_engine_step_makes_no_host_to_device_transfer():
 
 
 def test_gen_batch_log_carries_step_deltas():
-    """Each paged dispatch's log record holds its own steps and active
-    slot-steps (deltas of the engine's running counts), beside its slot
-    count, and the host time of those steps: 1 row x 2 candidates of 6
-    tokens take 5 steps in 4 slots."""
+    """Each paged dispatch's log record holds its own steps, active
+    slot-steps and live page-steps (deltas of the engine's running
+    counts), beside its slot count, and the host time of those steps: 1
+    row x 2 candidates of 6 tokens take 5 steps in 4 slots."""
     pp = ProteinPayload(jax.random.PRNGKey(0), reduced=True, length=6)
     mesh = _Mesh()
     at = len(gen_batch_log)
@@ -288,6 +399,9 @@ def test_gen_batch_log_carries_step_deltas():
     for rec in gen_batch_log[at:]:
         assert rec["bucket"] == 4
         assert (rec["steps"], rec["slot_steps"]) == (5, 2 * 5)
+        S = pp.gen_cfg.frontend_seq + 1
+        assert rec["page_steps"] == 2 * sum(-(-(S + i) // 8)
+                                            for i in range(1, 6))
         assert rec["step_host_s"] > 0
 
 

@@ -5,7 +5,9 @@ widths, the masked predict step at ``foldscore-m`` widths, and the
 finetune train step on one chip and data-parallel over four.
 
 Nothing runs: shapes come from ``jax.eval_shape`` and no array is placed
-on a described device. ``interpret=False`` is passed explicitly, because
+on a described device. The one exception runs only on a TPU and skips
+elsewhere: the compiled paged kernel against its vectorized twin.
+``interpret=False`` is passed explicitly, because
 ``jax.default_backend()`` is still the CPU here. The topology is described
 in a module fixture (skipping where it cannot be), and XLA's persistent
 compilation cache is off around these compiles: what they would write
@@ -80,12 +82,9 @@ def test_paged_decode_step_compiles(one_chip, page_size):
     assert eng.trace_counts["step"] == 1
 
 
-def test_paged_kernel_op_name(one_chip):
-    """The Pallas kernel's own ``name=`` names its op in the compiled
-    program, whatever function wraps it: the benchmark's trace readers
-    find the kernel by ``_paged_decode_attention``."""
+def _kernel_ops(one_chip, B, KV, G, hd, page, maxp, P):
+    """The op names of the compiled kernel's custom calls at these shapes."""
     from repro.kernels import paged_attention as pa
-    B, KV, G, hd, page, maxp, P = 4, 2, 2, 32, 8, 3, 13
     f = jax.jit(lambda q, k, v, bt, ln: pa.paged_decode_bkgh(
         q, k, v, bt, ln, page_size=page, interpret=False))
     spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
@@ -95,10 +94,66 @@ def test_paged_kernel_op_name(one_chip):
                    spec((P, KV, page, hd), jnp.bfloat16),
                    spec((B, maxp), jnp.int32),
                    spec((B,), jnp.int32)).compile().as_text()
-    ops = [line.split(" = ", 1)[0].split()[-1] for line in text.splitlines()
-           if "custom-call(" in line and "tpu_custom_call" in line]
+    return [line.split(" = ", 1)[0].split()[-1] for line in text.splitlines()
+            if "custom-call(" in line and "tpu_custom_call" in line]
+
+
+def test_paged_kernel_op_name(one_chip):
+    """The Pallas kernel's own ``name=`` names its op in the compiled
+    program, whatever function wraps it: the benchmark's trace readers
+    find the kernel by ``_paged_decode_attention``."""
+    ops = _kernel_ops(one_chip, 4, 2, 2, 32, 8, 3, 13)
     assert ops and all(o.startswith("%_paged_decode_attention")
                        for o in ops), ops
+
+
+# (B, KV, G, hd, page, maxp, P) for 32 slots: imrp-paged-mixlen's longest
+# engine, 26 pages over a pool of 833, all in one grid step per slot; rows
+# of 4160 tokens, whose pages exceed one grid step's VMEM budget and split
+# into chunks, at page 8 and 16; and the most pages one step takes, 128 of
+# 16 tokens
+CELL = (32, 4, 2, 32, 8, 26, 833)
+LONG = [(32, 4, 2, 32, 8, 520, 16641), (32, 4, 2, 32, 16, 260, 8321),
+        (32, 4, 2, 32, 16, 128, 4097)]
+
+
+@pytest.mark.parametrize("B,KV,G,hd,page,maxp,P", [CELL, *LONG])
+def test_paged_kernel_compiles_at_length(one_chip, B, KV, G, hd, page,
+                                         maxp, P):
+    """The kernel compiles for the chip, under its op name, at the cell's
+    rows and at rows far longer: a row's pages never take more VMEM than
+    ``pages_per_step`` allows."""
+    ops = _kernel_ops(one_chip, B, KV, G, hd, page, maxp, P)
+    assert ops and all(o.startswith("%_paged_decode_attention")
+                       for o in ops), ops
+
+
+@pytest.mark.parametrize("B,KV,G,hd,page,maxp,P", [CELL, LONG[0]])
+def test_paged_kernel_matches_ref_on_chip(B, KV, G, hd, page, maxp, P):
+    """On a TPU, the compiled kernel at the cell's shapes (32 slots of 26
+    pages, bf16) and at rows split into chunks against its vectorized
+    twin: inactive rows exactly zero, the rest within 1e-2. Off the chip
+    there is nothing to run."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs a TPU")
+    from repro.kernels import paged_attention as pa
+    rng = np.random.default_rng(18)
+    q, kp, vp = (jnp.asarray(rng.normal(size=s), jnp.bfloat16)
+                 for s in ((B, KV, G, hd), (P, KV, page, hd),
+                           (P, KV, page, hd)))
+    bt = jnp.asarray(rng.permutation(P - 1)[:B * maxp].reshape(B, maxp),
+                     jnp.int32)
+    lens = rng.integers(1, maxp * page + 1, size=B)
+    lens[:4] = 0, 1, page, maxp * page
+    lens = jnp.asarray(lens, jnp.int32)
+    got = np.asarray(jax.jit(functools.partial(
+        pa.paged_decode_bkgh, page_size=page, interpret=False))(
+            q, kp, vp, bt, lens), np.float32)
+    want = np.asarray(jax.jit(functools.partial(
+        pa.paged_decode_ref, page_size=page))(q, kp, vp, bt, lens),
+        np.float32)
+    assert not got[0].any()
+    np.testing.assert_allclose(got, want, atol=1e-2, rtol=1e-2)
 
 
 def test_masked_predict_compiles(one_chip):
